@@ -305,7 +305,7 @@ def run_permute_timing(K: int = 16, codecs=("identity", "bf16", "int8", "topk:0.
     16 shards oversubscribe one CPU, so these numbers are comparable
     run-to-run (and against each other) but NOT against the single-process
     gather rows."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.consensus import PermuteConsensus
@@ -316,7 +316,7 @@ def run_permute_timing(K: int = 16, codecs=("identity", "bf16", "int8", "topk:0.
             "`python benchmarks/combine_micro.py --permute-timing` (the flag "
             "must be on the command line before jax initializes)"
         )
-    mesh = jax.make_mesh((K,), ("data",))
+    mesh = jax.make_mesh((K,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     pK = _model_stack(jax.random.key(0), K)
     part = LayerPartition.build(jax.tree.map(lambda x: x[0], pK))
     topo = make_topology("ring", K)
@@ -338,7 +338,7 @@ def run_permute_timing(K: int = 16, codecs=("identity", "bf16", "int8", "topk:0.
             fns[(codec, path)] = jax.jit(
                 shard_map(
                     body, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
     return _time_paired(fns, pK, iters=5)
@@ -443,7 +443,7 @@ def run_sparse_paths(
         if sharded:
             from repro.launch.sharding import edge_stack_pspecs
 
-            mesh = jax.make_mesh((n_dev,), ("data",))
+            mesh = jax.make_mesh((n_dev,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
             pK = jax.tree.map(
                 lambda x: jax.device_put(x, NamedSharding(mesh, P("data"))), pK
             )
@@ -490,10 +490,7 @@ def run_sparse_paths(
             iters = 9 if K <= 16 else (5 if K <= 64 else 3)
             if time_dense and K <= dense_timed_max:
                 compiled = {k: f.lower(pK).compile() for k, f in fns.items()}
-                cost = {}
-                for k, ex in compiled.items():
-                    ca = ex.cost_analysis()
-                    cost[k] = ca[0] if isinstance(ca, list) else ca
+                cost = {k: ex.cost_analysis() for k, ex in compiled.items()}
                 row.update(
                     flops_dense=cost["dense"].get("flops", 0.0),
                     flops_edge=cost["edge"].get("flops", 0.0),

@@ -25,6 +25,7 @@ from repro.core.topology import PAPER_ER_SEED
 from repro.data import CifarLike, CifarLikeConfig, agent_minibatches
 from repro.models.resnet import init_resnet20, resnet20_accuracy, resnet20_loss
 from repro.optim import adamw, momentum
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run_experiment(args, topology_name: str, algorithm: str, data, shards, test):
@@ -81,6 +82,7 @@ def main(argv=None):
     )
     ap.add_argument("--out-csv", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.codec:
         from repro.comm import compression_ratio

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.core import DecentralizedTrainer, TrainerConfig, ring
 from repro.optim import momentum
+from repro.utils.compile_cache import enable_compile_cache
 
 K = 8
 DIM, CLASSES = 16, 4
@@ -65,6 +66,7 @@ def accuracy(p, x, y):
 
 
 def main():
+    enable_compile_cache()
     shards, (xt, yt) = make_data()
     xs = jnp.stack([jnp.asarray(x) for x, _ in shards])
     ys = jnp.stack([jnp.asarray(y) for _, y in shards])

@@ -26,6 +26,7 @@ from repro.models.registry import build_bundle
 from repro.optim import adamw
 from repro.optim.schedule import linear_warmup_cosine
 from repro.utils import tree_size
+from repro.utils.compile_cache import enable_compile_cache
 
 PRESETS = {
     "tiny": dict(layers=2, d_model=128, heads=4, kv=2, d_ff=384, vocab=512, agents=4,
@@ -65,6 +66,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--eval-every", type=int, default=25)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     p = PRESETS[args.preset]
     cfg = make_cfg(p)

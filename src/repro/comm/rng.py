@@ -67,8 +67,11 @@ def counter_bits(w0, w1, idx):
 def bits_to_uniform(bits):
     """uint32 -> f32 U[0, 1): top 24 bits scaled by 2^-24 (every value is an
     exact f32; 1.0 is never produced, so ``floor(x/s + u)`` never rounds a
-    representable value past its ceiling)."""
-    return (bits >> np.uint32(8)).astype(jnp.float32) * _INV24
+    representable value past its ceiling).  The 24-bit value goes through
+    an int32 bitcast (exact: it is below 2^31) because Mosaic has no
+    uint32 -> f32 conversion."""
+    top = jax.lax.bitcast_convert_type(bits >> np.uint32(8), jnp.int32)
+    return top.astype(jnp.float32) * _INV24
 
 
 def key_words(key):

@@ -370,9 +370,7 @@ def _dequant_combine_slab_kernels(layout, A_off, wire):
     A_blocks = jnp.take(
         A_off.astype(jnp.float32), jnp.asarray(layout.block_layer), axis=0
     )
-    col_seg = jnp.asarray(
-        layout.col_scale_seg.reshape(layout.n_blocks, layout.lane)
-    )
+    col_seg, _, _ = _layout_col_maps(layout)
     out = slab_dequant_combine(A_blocks, wire.s, col_seg, layout.join(wire.q))
     return layout.split(out)
 
@@ -380,12 +378,7 @@ def _dequant_combine_slab_kernels(layout, A_off, wire):
 def _layout_col_maps(layout):
     """The static per-column maps the fused encode kernels consume, in
     (n_blocks, lane) form: scale segment, owning leaf, intra-leaf index."""
-    nb, lane = layout.n_blocks, layout.lane
-    return (
-        jnp.asarray(layout.col_scale_seg.reshape(nb, lane)),
-        jnp.asarray(layout.col_leaf.reshape(nb, lane)),
-        jnp.asarray(layout.col_idx.reshape(nb, lane)),
-    )
+    return layout.col_maps()
 
 
 def _fused_coded_round(
@@ -1263,7 +1256,7 @@ def gather_consensus_rounds(
                     A = drt_mod.drt_mixing_matrices(d2, n2, C_r, cfg)
                 if robust_on:
                     A = _rw_dense(A)
-                M_new = jnp.einsum("pij,pjk->pik", M, A)
+                M_new = jnp.einsum("pij,pjk->pik", M, A, precision=packing.HIGHEST)
                 mom_sq = jnp.zeros((), jnp.float32)
                 if use_mom:
                     dM = M - M_prev
@@ -1275,7 +1268,7 @@ def gather_consensus_rounds(
                             (beta * beta)
                             * jnp.sum(
                                 jnp.diagonal(
-                                    packing.gram_update(G0, dM), axis1=1, axis2=2
+                                    packing.gram_update(G0[0], dM), axis1=1, axis2=2
                                 )
                             )
                             / float(K)
@@ -1371,7 +1364,7 @@ def gather_consensus_rounds(
                     ),
                     byzantine_weight_mass=jnp.zeros((), jnp.float32),
                 )
-                return (G2, jnp.einsum("pij,pjk->pik", M, A), A), m
+                return (G2, jnp.einsum("pij,pjk->pik", M, A, precision=packing.HIGHEST), A), m
 
             (_, M, A_last), metrics = _scan_rounds(
                 exact_body,
@@ -1388,7 +1381,7 @@ def gather_consensus_rounds(
                 A = jnp.broadcast_to(metro_r, (L, K, K))
                 if robust_on:
                     A = _rw_dense(A)
-                return (jnp.einsum("pij,pjk->pik", M, A), A), None
+                return (jnp.einsum("pij,pjk->pik", M, A, precision=packing.HIGHEST), A), None
 
             (M, A_last), _ = _scan_rounds(
                 exact_body,
@@ -1408,7 +1401,7 @@ def gather_consensus_rounds(
                     A = _rw_dense(A)
                 return (
                     packing.gram_update(G, A),
-                    jnp.einsum("pij,pjk->pik", M, A),
+                    jnp.einsum("pij,pjk->pik", M, A, precision=packing.HIGHEST),
                     A,
                 ), None
 
@@ -2175,12 +2168,12 @@ class PermuteConsensus:
             if self.use_kernels:
                 from repro.kernels import slab_source_combine
 
-                # ONE whole-slab launch per round: sources stacked as flat
-                # (1+n, D) slabs (self = full precision), per-block weights
-                # gathered from the static block->layer map
-                srcs_slab = jnp.stack(
-                    [layout.join(regions)] + [layout.join(rv) for rv in recvs]
-                )
+                # ONE whole-slab launch per round over the 1+n flat source
+                # slabs (self = full precision), per-block weights gathered
+                # from the static block->layer map
+                srcs_slab = [layout.join(regions)] + [
+                    layout.join(rv) for rv in recvs
+                ]
                 w_blocks = jnp.take(
                     w_all.astype(jnp.float32),
                     jnp.asarray(layout.block_layer),

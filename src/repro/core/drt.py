@@ -150,6 +150,74 @@ def drt_weights_from_params(partition, params_K, C, cfg: DRTConfig) -> jax.Array
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class EdgeSegments:
+    """Gathers by edge endpoint and per-destination reductions over a padded
+    directed edge list of ``K`` agents.
+
+    ``dense=False`` spells them as XLA gathers and scatter-reduces: O(E),
+    what the jnp edge path runs.  ``dense=True`` spells the same operations
+    as one-hot matmuls and masked reductions over (K, E) incidence masks:
+    O(K E), the only form Mosaic lowers inside a kernel, where K is the
+    small in-kernel agent count.  Gathers and min/max agree bit for bit;
+    sums agree up to the order of addition at in-degree > 2.
+    """
+
+    src: jax.Array
+    dst: jax.Array
+    K: int
+    dense: bool = False
+
+    def _onehot(self, idx):  # (K, E) f32: [k, e] = (idx[e] == k)
+        E = idx.shape[-1]
+        iota = jax.lax.broadcasted_iota(jnp.int32, (self.K, E), 0)
+        return (iota == idx.reshape(1, E)).astype(jnp.float32)
+
+    def _gather(self, x: jax.Array, idx: jax.Array) -> jax.Array:
+        """``x[..., idx]``: (..., K) -> (..., E)."""
+        if not self.dense:
+            return x[..., idx]
+        x2 = x.reshape(-1, self.K).astype(jnp.float32)
+        out = jnp.dot(
+            x2, self._onehot(idx),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        return out.reshape(*x.shape[:-1], idx.shape[-1])
+
+    def at_src(self, x):
+        return self._gather(x, self.src)
+
+    def at_dst(self, x):
+        return self._gather(x, self.dst)
+
+    def _reduce(self, v, init, op):
+        # v (..., E) -> (..., K), init where no edge lands
+        if not self.dense:
+            out = jnp.full((*v.shape[:-1], self.K), init, v.dtype)
+            return getattr(out.at[..., self.dst], op)(v)
+        onehot = self._onehot(self.dst)
+        if op == "add":
+            v2 = v.reshape(-1, v.shape[-1]).astype(jnp.float32)
+            out = jax.lax.dot_general(
+                v2, onehot, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            ) + init
+            return out.reshape(*v.shape[:-1], self.K)
+        masked = jnp.where(onehot > 0.0, v[..., None, :], init)
+        return jnp.min(masked, -1) if op == "min" else jnp.max(masked, -1)
+
+    def segment_sum(self, v, init=0.0):
+        return self._reduce(v, init, "add")
+
+    def segment_min(self, v, init):
+        return self._reduce(v, init, "min")
+
+    def segment_max(self, v, init):
+        return self._reduce(v, init, "max")
+
+
 def drt_edge_mixing(
     d2_e: jax.Array,
     n2: jax.Array,
@@ -158,6 +226,7 @@ def drt_edge_mixing(
     w: jax.Array,
     cfg: DRTConfig,
     K: int,
+    dense: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Eqs. (12)-(14) on a padded directed edge list — O(|E| L) not O(K^2 L).
 
@@ -174,21 +243,25 @@ def drt_edge_mixing(
     Returns ``(A_self (L, K), A_e (L, E))`` — column-stochastic:
     ``A_self[:, k] + sum_{e: dst[e]==k} A_e[:, e] == 1``; an isolated agent
     gets ``A_self = 1`` (the identity column), matching the dense path.
+    ``dense=True`` runs the gathers and segment reductions as
+    :class:`EdgeSegments` masked reductions (inside a Pallas kernel).
     """
     L = d2_e.shape[0]
     N = cfg.resolve_N(K)
+    seg = EdgeSegments(src, dst, K, dense)
     d2_e = d2_e.astype(jnp.float32)
     n2 = n2.astype(jnp.float32)
     w = jnp.asarray(w, jnp.float32)
     mask = w > 0.0
 
     # eq. (14) per edge: ratio against the SOURCE agent's layer norms
-    ratio = d2_e / (n2[:, src] + cfg.kappa)
+    n2_src = seg.at_src(n2)  # (L, E)
+    ratio = d2_e / (n2_src + cfg.kappa)
     log_prod = jnp.sum(jnp.log1p(ratio), axis=0) + (L + 1) * jnp.log(2.0)  # (E,)
     if cfg.weight_mode == "paper":
         log_denom = jnp.log(d2_e + cfg.kappa)
     elif cfg.weight_mode == "exact_grad":
-        log_denom = jnp.log(n2[:, src] + cfg.kappa + d2_e)
+        log_denom = jnp.log(n2_src + cfg.kappa + d2_e)
     else:
         raise ValueError(f"unknown weight_mode {cfg.weight_mode!r}")
     log_w = jnp.log(jnp.where(mask, w, 1.0))
@@ -196,21 +269,17 @@ def drt_edge_mixing(
     log_a = jnp.where(mask[None], log_a, _NEG_INF)
 
     # eq. (13) clip: min positive entry per (p, dst) column via segment-min
-    log_min = jnp.full((L, K), -_NEG_INF, jnp.float32).at[:, dst].min(
-        jnp.where(mask[None], log_a, -_NEG_INF)
-    )
-    log_clipped = jnp.minimum(log_a, jnp.log(N) + log_min[:, dst])
+    log_min = seg.segment_min(jnp.where(mask[None], log_a, -_NEG_INF), -_NEG_INF)
+    log_clipped = jnp.minimum(log_a, jnp.log(N) + seg.at_dst(log_min))
     log_clipped = jnp.where(mask[None], log_clipped, _NEG_INF)
 
     # self weight: a~_kk = c_kk/(n_k - 1) * sum over incoming edges
     # (two-pass segment logsumexp: scatter-max shift, then scatter-sum)
-    n_k = 1.0 + jnp.zeros((K,), jnp.float32).at[dst].add(mask.astype(jnp.float32))
+    n_k = 1.0 + seg.segment_sum(mask.astype(jnp.float32))
     denom = jnp.maximum(n_k - 1.0, 1.0)
-    m1 = jnp.full((L, K), _NEG_INF, jnp.float32).at[:, dst].max(
-        jnp.where(mask[None], log_clipped, _NEG_INF)
-    )
-    sumexp = jnp.zeros((L, K), jnp.float32).at[:, dst].add(
-        jnp.where(mask[None], jnp.exp(log_clipped - m1[:, dst]), 0.0)
+    m1 = seg.segment_max(jnp.where(mask[None], log_clipped, _NEG_INF), _NEG_INF)
+    sumexp = seg.segment_sum(
+        jnp.where(mask[None], jnp.exp(log_clipped - seg.at_dst(m1)), 0.0)
     )
     log_sum = jnp.where(sumexp > 0.0, m1 + jnp.log(jnp.maximum(sumexp, 1e-30)),
                         _NEG_INF)
@@ -219,9 +288,9 @@ def drt_edge_mixing(
     # eq. (12) normalize: shifted exp over {self} u {incoming edges}
     m = jnp.maximum(log_self, m1)
     a_self = jnp.exp(log_self - m)
-    a_e = jnp.where(mask[None], jnp.exp(log_clipped - m[:, dst]), 0.0)
-    colsum = a_self + jnp.zeros((L, K), jnp.float32).at[:, dst].add(a_e)
-    return a_self / colsum, a_e / colsum[:, dst]
+    a_e = jnp.where(mask[None], jnp.exp(log_clipped - seg.at_dst(m)), 0.0)
+    colsum = a_self + seg.segment_sum(a_e)
+    return a_self / colsum, a_e / seg.at_dst(colsum)
 
 
 def edge_mixing_dense(

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.drt import EdgeSegments
 from repro.core.topology import Topology, make_topology
 
 
@@ -73,7 +74,7 @@ class EdgeStacks(NamedTuple):
 
 
 def metropolis_edge_weights(
-    src: jax.Array, dst: jax.Array, w: jax.Array, K: int
+    src: jax.Array, dst: jax.Array, w: jax.Array, K: int, dense: bool = False
 ) -> tuple[jax.Array, jax.Array]:
     """Metropolis-Hastings weights (eq. 5) on a padded directed edge list.
 
@@ -82,13 +83,16 @@ def metropolis_edge_weights(
     agent ``dst[e]`` applies to ``src[e]``'s iterate, ``m_self[k]`` the
     diagonal.  Padding edges (``w == 0``) get weight 0 and an isolated agent
     keeps the identity column, matching the dense construction.
+    ``dense=True`` runs the gathers and segment sums as masked reductions
+    (:class:`repro.core.drt.EdgeSegments`, inside a Pallas kernel).
     """
+    seg = EdgeSegments(src, dst, K, dense)
     mask = (jnp.asarray(w, jnp.float32) > 0.0).astype(jnp.float32)
-    deg = jnp.ones((K,), jnp.float32).at[dst].add(mask)  # n_k incl. self loop
+    deg = seg.segment_sum(mask, 1.0)  # n_k incl. self loop
     m_e = jnp.where(
-        mask > 0.0, 1.0 / jnp.maximum(deg[src], deg[dst]), 0.0
+        mask > 0.0, 1.0 / jnp.maximum(seg.at_src(deg), seg.at_dst(deg)), 0.0
     )
-    m_self = 1.0 - jnp.zeros((K,), jnp.float32).at[dst].add(m_e)
+    m_self = 1.0 - seg.segment_sum(m_e)
     return m_self, m_e
 
 

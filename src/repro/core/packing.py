@@ -95,10 +95,13 @@ from repro.comm.codec import (
     topk_threshold,
 )
 from repro.comm.rng import counter_uniform, key_words, uniform_from_words
-from repro.utils.pytree import LayerPartition
+from repro.utils.pytree import LayerPartition, sq_dists_from_grams
 
 PyTree = Any
 F32 = jnp.float32
+# consensus contractions mix f32 parameters: on TPU the default matmul pass
+# rounds operands to bf16, which would drop parameter bits every round
+HIGHEST = jax.lax.Precision.HIGHEST
 
 LANES = 128  # TPU lane width; layer segments are padded to a multiple of this
 
@@ -227,6 +230,32 @@ class SlabLayout:
                         -1
                     ].flat_idx
         return leaf, idx
+
+    def col_maps(self) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """:attr:`col_scale_seg`, :attr:`col_leaf` and :attr:`col_idx` as
+        ``(n_blocks, lane)`` arrays (int32, int32, uint32), built from fills
+        and iotas over the layout's runs of columns, so that a jitted
+        caller holds no D-sized constant (three 1.6 GB literals at the width
+        of one h2o-danube-3-4b layer)."""
+        seg, leaf, idx = [], [], []
+        for grp in self.groups:
+            last = grp.float_leaves[-1]
+            for j in range(grp.n_slots):
+                for plan in grp.float_leaves:
+                    sid = plan.scale_seg0 + (j if plan.scale_per_slot else 0)
+                    seg.append(jnp.full((plan.width,), sid, jnp.int32))
+                    leaf.append(jnp.full((plan.width,), plan.flat_idx, jnp.int32))
+                    idx.append(
+                        j * plan.width + jnp.arange(plan.width, dtype=jnp.uint32)
+                    )
+                pad = grp.s_pad - grp.s
+                if pad:
+                    # padding inherits the last leaf's segment and leaf
+                    seg.append(jnp.full((pad,), seg[-1][0], jnp.int32))
+                    leaf.append(jnp.full((pad,), last.flat_idx, jnp.int32))
+                    idx.append(jnp.zeros((pad,), jnp.uint32))
+        shape = (self.n_blocks, self.lane)
+        return tuple(jnp.concatenate(m).reshape(shape) for m in (seg, leaf, idx))
 
     # -- batch handling -------------------------------------------------------
 
@@ -370,17 +399,24 @@ class SlabLayout:
         return jnp.concatenate(outs, axis=0)
 
     def gram(self, regions: tuple) -> jax.Array:
-        """Per-layer agent Gram matrices ``(L, K, K)`` from slot-major
-        ``(n_slots, K, s_pad)`` regions: ONE batched matmul per group
-        (leading batch dim, no transposes) instead of one einsum per leaf."""
+        """Per-layer agent Gram matrices from slot-major ``(n_slots, K,
+        s_pad)`` regions, of the raw regions and of the regions less agent
+        0's, stacked ``(2, L, K, K)``: ONE batched matmul per group each
+        (leading batch dim, no transposes).  The second keeps the distances
+        that rounding takes from the first once the agents nearly agree
+        (:func:`repro.utils.pytree.sq_dists_from_grams`); both follow
+        :func:`gram_update`, since a column-stochastic combine maps iterates
+        less a fixed vector to iterates less the same vector."""
         grams = []
-        for region in regions:
-            grams.append(
+        for x in (regions, tuple(r.astype(F32) - r[:, :1].astype(F32) for r in regions)):
+            grams.append(jnp.concatenate([
                 jnp.einsum(
-                    "nks,njs->nkj", region, region, preferred_element_type=F32
+                    "nks,njs->nkj", region, region,
+                    precision=HIGHEST, preferred_element_type=F32,
                 )
-            )
-        return jnp.concatenate(grams, axis=0)  # (L, K, K)
+                for region in x
+            ], axis=0))
+        return jnp.stack(grams)  # (2, L, K, K)
 
     def pairwise_sq_dists(self, regions: tuple) -> tuple[jax.Array, jax.Array]:
         """All-pairs per-layer squared distances via the Gram trick.
@@ -508,6 +544,7 @@ class SlabLayout:
                 jax.lax.dot_general(
                     A_g, region,
                     (((1,), (1,)), ((0,), (0,))),  # contract l, batch over n
+                    precision=HIGHEST,
                     preferred_element_type=F32,
                 )  # (n, k, s)
             )
@@ -533,7 +570,7 @@ class SlabLayout:
                 )  # (n, *batch, width)
                 mixed = jax.lax.dot_general(
                     A_g, piece, (((1,), (1,)), ((0,), (0,))),
-                    preferred_element_type=F32,
+                    precision=HIGHEST, preferred_element_type=F32,
                 )  # (n, *batch=k, width)
                 mixed = jnp.moveaxis(mixed, 0, -2)  # (*batch, n, width)
                 new_leaves[plan.local_idx] = mixed.reshape(
@@ -561,11 +598,11 @@ class SlabLayout:
 
 
 def gram_sq_dists(gram: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Distance statistics from per-layer Gram matrices:
-    ``d2[p,l,k] = n2[p,l] + n2[p,k] - 2 gram[p,l,k]`` (clamped at 0)."""
-    n2 = jnp.diagonal(gram, axis1=1, axis2=2)
-    d2 = n2[:, :, None] + n2[:, None, :] - 2.0 * gram
-    return jnp.maximum(d2, 0.0), n2
+    """Distance statistics ``(d2 (L, K, K), n2 (L, K))`` from the
+    ``(2, L, K, K)`` Gram stack of :meth:`SlabLayout.gram`:
+    ``d2[p,l,k] = n2[p,l] + n2[p,k] - 2 gram[p,l,k]`` (clamped at 0), from
+    the centred Gram where the raw one has lost it to rounding."""
+    return sq_dists_from_grams(gram[0], gram[1])
 
 
 def gram_update(gram: jax.Array, A: jax.Array) -> jax.Array:
@@ -573,10 +610,14 @@ def gram_update(gram: jax.Array, A: jax.Array) -> jax.Array:
     layer implies ``G' = A^T G A``.  With an exact (uncoded) exchange this
     lets a whole round-set run on (L, K, K) matrices — one Gram pass before
     the rounds, one combine after — instead of two passes over all D
-    parameters per round."""
+    parameters per round.  A ``(2, L, K, K)`` :meth:`SlabLayout.gram`
+    stack is updated member by member."""
+    if gram.ndim == 4:
+        return jnp.stack([gram_update(g, A) for g in gram])
     A = A.astype(F32)
     return jnp.einsum(
-        "pia,pij,pjb->pab", A, gram, A, preferred_element_type=F32
+        "pia,pij,pjb->pab", A, gram, A,
+        precision=HIGHEST, preferred_element_type=F32,
     )
 
 
@@ -586,7 +627,9 @@ def gram_disagreement(gram: jax.Array) -> jax.Array:
 
     Per layer: ``mean_k G[kk] - mean_{kl} G[kl]`` — the telemetry path's
     free ride on the exact consensus recurrence (no extra pass over the D
-    parameters; :func:`region_disagreement` is the direct oracle)."""
+    parameters; :func:`region_disagreement` is the direct oracle).  Reads
+    the raw Gram of a :meth:`SlabLayout.gram` stack."""
+    gram = gram[0]
     diag = jnp.diagonal(gram, axis1=1, axis2=2)  # (L, K)
     return jnp.sum(jnp.mean(diag, axis=-1) - jnp.mean(gram, axis=(-2, -1)))
 

@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (the body executes in
-Python/XLA-CPU and is validated against the ref.py oracles); on TPU pass
-``interpret=False`` (or set REPRO_PALLAS_INTERPRET=0).
+Off a TPU the kernels run in interpret mode (the body executes in
+Python/XLA-CPU and is validated against the ref.py oracles); on a TPU they
+compile under Mosaic (``repro.kernels.runtime.default_interpret``).
 """
 from __future__ import annotations
 
@@ -74,7 +74,7 @@ def slab_dequant_combine(A_blocks, scales, col_seg, q_slab, *, interpret: bool |
 
 
 def slab_source_combine(w_blocks, srcs, *, interpret: bool | None = None):
-    """Per-layer weighted combine over N stacked source slabs, ONE launch."""
+    """Per-layer weighted combine over N source slabs, ONE launch."""
     return _slab_source_combine(
         w_blocks, srcs, interpret=interpret
     )

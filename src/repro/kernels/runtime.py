@@ -1,17 +1,8 @@
 """Kernel runtime policy shared by every Pallas entry point.
 
-Every kernel in this package used to hardcode ``interpret: bool = True`` in
-its own signature — correct on the CPU containers the tier-1 suite runs on,
-but it meant a real TPU run had to thread ``interpret=False`` through every
-call site (and a forgotten one silently ran the Python interpreter on
-device).  :func:`default_interpret` centralizes the decision:
-
-  * ``REPRO_PALLAS_INTERPRET`` (``"0"``/``"1"``) always wins — the explicit
-    escape hatch for debugging a compiled kernel in interpret mode or
-    force-compiling on an unsupported backend;
-  * otherwise interpret mode is ON everywhere except a real TPU backend
-    (Pallas TPU kernels only *compile* under Mosaic; CPU/GPU backends run
-    the interpreter).
+Pallas TPU kernels compile only under Mosaic, on a TPU backend; every other
+backend runs their bodies in the Pallas interpreter.  :func:`default_interpret`
+is that rule and nothing else: interpret mode means exactly "not on a TPU".
 
 Kernel entry points take ``interpret: bool | None = None`` and resolve
 ``None`` through this helper at trace time, so a bare call does the right
@@ -19,20 +10,14 @@ thing on any backend while tests can still pin either mode explicitly.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 
 __all__ = ["default_interpret", "resolve_interpret"]
 
 
 def default_interpret() -> bool:
-    """True when Pallas kernels should run in interpret mode on this backend
-    (everywhere except real TPUs), unless ``REPRO_PALLAS_INTERPRET`` says
-    otherwise."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
+    """True when Pallas kernels run in interpret mode: on any backend but
+    a TPU."""
     return jax.default_backend() != "tpu"
 
 

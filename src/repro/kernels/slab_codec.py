@@ -17,7 +17,8 @@ ONE ``pallas_call``:
                              ``col_leaf``/``col_idx`` maps + per-column scale
                              reconstruction from ``col_scale_seg``; bf16/f16:
                              the cast round-trip) and accumulates the
-                             per-DRT-layer Gram matrices into a VMEM scratch
+                             per-DRT-layer Gram matrices (raw and less agent
+                             0's) into a VMEM scratch
                              — the decoded (and the f32 wire) slab never
                              exist in HBM;
                            * the first phase-1 step runs the FULL DRT
@@ -64,7 +65,10 @@ Everything per-COLUMN — scale broadcast, RNG, quantize, dequantize, combine
 — happens in-kernel.
 
 Interpret mode on CPU is what the tier-1 tests pin (as for every kernel in
-this package); on TPU the grid runs compiled.  Use through the
+this package); on TPU the grid runs compiled by Mosaic.  Tile alignment:
+``block_layer`` sits whole in SMEM and each step reads its own entry; the
+per-column tables arrive as ``(n_blocks, 1, lane)`` arrays so a block's
+``(1, lane)`` row spans the array's last two dims.  Use through the
 ``repro.kernels`` (ops.py) wrappers.
 """
 from __future__ import annotations
@@ -79,39 +83,43 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.comm.rng import bits_to_uniform, counter_bits
 from repro.core import drt as drt_mod
 from repro.kernels.runtime import resolve_interpret
+from repro.utils.pytree import sq_dists_from_grams
 
 F32 = jnp.float32
 
 LANES = 128  # column-block width; SlabLayout pads every layer segment to it
 QMAX = 127.0
+HIGHEST = jax.lax.Precision.HIGHEST
 
 _CAST = {"bf16": jnp.bfloat16, "f16": jnp.float16}
 
 
 def _gather_key_words(w_ref, leaf_cols):
-    """(K, LANES) uint32 key words for this block's columns: select each
-    column's owning-leaf word from the (K, n_leaves) table.  A static select
-    chain over the (small) leaf count — uint32 has no MXU path and dynamic
-    gathers don't vectorize on TPU."""
+    """(K, LANES) uint32 key words for this block's columns (``leaf_cols``:
+    (1, LANES)): select each column's owning-leaf word from the
+    (K, n_leaves) table.  A static select chain over the (small) leaf count
+    — uint32 has no MXU path and dynamic gathers don't vectorize on TPU."""
     words = w_ref[...]  # (K, n_leaves) uint32
     n_leaves = words.shape[1]
-    out = jnp.broadcast_to(
-        words[:, 0][:, None], (words.shape[0], leaf_cols.shape[-1])
-    )
+    out = jnp.broadcast_to(words[:, 0:1], (words.shape[0], leaf_cols.shape[-1]))
     for l in range(1, n_leaves):
-        out = jnp.where((leaf_cols == l)[None, :], words[:, l][:, None], out)
+        out = jnp.where(leaf_cols == l, words[:, l : l + 1], out)
     return out
 
 
 def _scale_cols(s_ref, seg_cols):
-    """(K, LANES) per-column scales via the one-hot segment matmul (exact:
-    one unit product per column; MXU-friendly)."""
+    """(K, LANES) per-column scales via the one-hot segment matmul over the
+    (1, LANES) ``seg_cols`` (exact at HIGHEST precision: one unit product
+    per column; MXU-friendly)."""
     n_segs = s_ref.shape[1]
     onehot = (
-        seg_cols[None, :]
+        seg_cols
         == jax.lax.broadcasted_iota(jnp.int32, (n_segs, seg_cols.shape[-1]), 0)
     ).astype(F32)
-    return jnp.dot(s_ref[...].astype(F32), onehot, preferred_element_type=F32)
+    return jnp.dot(
+        s_ref[...].astype(F32), onehot,
+        precision=HIGHEST, preferred_element_type=F32,
+    )
 
 
 def _quant_block(x, s_cols, u):
@@ -124,11 +132,11 @@ def _int8_wire_block(x, quant_refs):
     """(quantized values f32, per-column scales) of this block — the
     receiver's decoded view is their product."""
     s_ref, seg_ref, leaf_ref, idx_ref, w0_ref, w1_ref = quant_refs
-    leaf_cols = leaf_ref[0]
+    leaf_cols = leaf_ref[...]
     k0 = _gather_key_words(w0_ref, leaf_cols)
     k1 = _gather_key_words(w1_ref, leaf_cols)
-    u = bits_to_uniform(counter_bits(k0, k1, idx_ref[0][None, :]))
-    s_cols = _scale_cols(s_ref, seg_ref[0])
+    u = bits_to_uniform(counter_bits(k0, k1, idx_ref[...]))
+    s_cols = _scale_cols(s_ref, seg_ref[...])
     return _quant_block(x, s_cols, u), s_cols
 
 
@@ -139,10 +147,10 @@ def _combine_block(A, dec, x):
     eye = jnp.eye(K, dtype=F32)
     off = jax.lax.dot_general(
         A * (1.0 - eye), dec, (((0,), (0,)), ((), ())),
-        preferred_element_type=F32,
+        precision=HIGHEST, preferred_element_type=F32,
     )
-    diag = jnp.sum(A * eye, axis=0)  # (K,) diagonal without a gather
-    return off + diag[:, None] * x
+    diag = jnp.sum(A * eye, axis=1, keepdims=True)  # (K, 1) without a gather
+    return off + diag * x
 
 
 def _encode_combine_kernel(mode, algorithm, kappa, N_clip, weight_mode, *refs):
@@ -172,7 +180,7 @@ def _encode_combine_kernel(mode, algorithm, kappa, N_clip, weight_mode, *refs):
 
     ph = pl.program_id(0)
     i = pl.program_id(1)
-    p = bl_ref[0]  # this block's DRT layer
+    p = bl_ref[i]  # this block's DRT layer
 
     @pl.when(ph == 0)
     def _gram_phase():
@@ -180,10 +188,14 @@ def _encode_combine_kernel(mode, algorithm, kappa, N_clip, weight_mode, *refs):
         def _init():
             G_scr[...] = jnp.zeros_like(G_scr)
 
-        Gp = jax.lax.dot_general(
-            dec, dec, (((1,), (1,)), ((), ())), preferred_element_type=F32
-        )  # (K, K) partial Gram of this block's layer
-        G_scr[pl.ds(p, 1)] = G_scr[pl.ds(p, 1)] + Gp[None]
+        # partial Grams of this block's layer, raw and less agent 0's (the
+        # two SlabLayout.pairwise_sq_dists reads)
+        for j, x in enumerate((dec, dec - dec[0:1])):
+            Gp = jax.lax.dot_general(
+                x, x, (((1,), (1,)), ((), ())),
+                precision=HIGHEST, preferred_element_type=F32,
+            )  # (K, K)
+            G_scr[j, pl.ds(p, 1)] = G_scr[j, pl.ds(p, 1)] + Gp[None]
 
     @pl.when(jnp.logical_and(ph == 1, i == 0))
     def _mixing():
@@ -191,9 +203,7 @@ def _encode_combine_kernel(mode, algorithm, kappa, N_clip, weight_mode, *refs):
         # — the same repro.core.drt code the jnp path runs, traced in-kernel.
         # A lands in the (whole-array, VMEM-resident) second OUTPUT, which
         # phase-1 blocks read back — the engine returns it as A_last
-        G = G_scr[...]  # (L, K, K)
-        n2 = jnp.sum(G * jnp.eye(G.shape[1], dtype=F32)[None], axis=2)
-        d2 = jnp.maximum(n2[:, :, None] + n2[:, None, :] - 2.0 * G, 0.0)
+        d2, n2 = sq_dists_from_grams(G_scr[0], G_scr[1])  # (L, K, K), (L, K)
         C = mix_ref[...].astype(F32)
         log_a = drt_mod.drt_log_unnormalized(d2, n2, C, kappa, weight_mode)
         A_ref[...] = drt_mod.drt_normalize(
@@ -262,7 +272,7 @@ def slab_encode_combine(
     grid = (2, nb) if drt else (1, nb)
 
     in_specs = [
-        pl.BlockSpec((1,), lambda ph, i: (i,), memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),  # whole block->layer map
         pl.BlockSpec((K, lane), lambda ph, i: (0, i)),
     ]
     operands = [jnp.asarray(block_layer, jnp.int32), slab.astype(F32)]
@@ -272,17 +282,13 @@ def slab_encode_combine(
         n_leaves = w0.shape[-1]
         in_specs += [
             pl.BlockSpec((K, n_segs), lambda ph, i: (0, 0)),
-            pl.BlockSpec((1, lane), lambda ph, i: (i, 0)),
-            pl.BlockSpec((1, lane), lambda ph, i: (i, 0)),
-            pl.BlockSpec((1, lane), lambda ph, i: (i, 0)),
+            *[pl.BlockSpec((None, 1, lane), lambda ph, i: (i, 0, 0))] * 3,
             pl.BlockSpec((K, n_leaves), lambda ph, i: (0, 0)),
             pl.BlockSpec((K, n_leaves), lambda ph, i: (0, 0)),
         ]
         operands += [
             scales.astype(F32),
-            col_seg.astype(jnp.int32),
-            col_leaf.astype(jnp.int32),
-            col_idx.astype(jnp.uint32),
+            *_block_rows(col_seg, col_leaf, col_idx),
             w0.astype(jnp.uint32),
             w1.astype(jnp.uint32),
         ]
@@ -320,7 +326,8 @@ def slab_encode_combine(
                 jax.ShapeDtypeStruct((K, D), F32),
                 jax.ShapeDtypeStruct((num_layers, K, K), F32),
             ),
-            scratch_shapes=[pltpu.VMEM((num_layers, K, K), F32)],  # Gram acc
+            # raw and centred Gram accumulators
+            scratch_shapes=[pltpu.VMEM((2, num_layers, K, K), F32)],
             interpret=resolve_interpret(interpret),
         )(*operands)
         return out, A
@@ -333,6 +340,17 @@ def slab_encode_combine(
         interpret=resolve_interpret(interpret),
     )(*operands)
     return out, jnp.broadcast_to(mix.astype(F32), (num_layers, K, K))
+
+
+def _block_rows(col_seg, col_leaf, col_idx):
+    """The (n_blocks, lane) column tables as (n_blocks, 1, lane) arrays, so
+    each grid step's (1, lane) block spans the last two dims (Mosaic tiling)."""
+    nb, lane = col_seg.shape
+    return (
+        col_seg.astype(jnp.int32).reshape(nb, 1, lane),
+        col_leaf.astype(jnp.int32).reshape(nb, 1, lane),
+        col_idx.astype(jnp.uint32).reshape(nb, 1, lane),
+    )
 
 
 def slab_cast_combine(block_layer, slab, mix, *, dtype="bf16", **kw):
@@ -390,9 +408,7 @@ def slab_quant_encode(
         in_specs=[
             pl.BlockSpec((K, lane), lambda i: (0, i)),
             pl.BlockSpec((K, n_segs), lambda i: (0, 0)),
-            pl.BlockSpec((1, lane), lambda i: (i, 0)),
-            pl.BlockSpec((1, lane), lambda i: (i, 0)),
-            pl.BlockSpec((1, lane), lambda i: (i, 0)),
+            *[pl.BlockSpec((None, 1, lane), lambda i: (i, 0, 0))] * 3,
             pl.BlockSpec((K, n_leaves), lambda i: (0, 0)),
             pl.BlockSpec((K, n_leaves), lambda i: (0, 0)),
         ],
@@ -402,9 +418,7 @@ def slab_quant_encode(
     )(
         slab.astype(F32),
         scales.astype(F32),
-        col_seg.astype(jnp.int32),
-        col_leaf.astype(jnp.int32),
-        col_idx.astype(jnp.uint32),
+        *_block_rows(col_seg, col_leaf, col_idx),
         w0.astype(jnp.uint32),
         w1.astype(jnp.uint32),
     )

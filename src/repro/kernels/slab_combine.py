@@ -20,9 +20,9 @@ and the grid streams (mixing block, slab block) pairs through the MXU:
                             static column->scale-segment map via a one-hot
                             matmul (dynamic gathers don't vectorize on TPU),
                             so the dequantized f32 neighbours never hit HBM.
-  ``slab_source_combine``   out[c] = sum_n w[n, layer(c)] * srcs[n, c]
+  ``slab_source_combine``   out[c] = sum_n w[n, layer(c)] * srcs[n][c]
                             — the permute engine's neighbour combine over the
-                            (1 + n_nbrs) stacked source slabs.
+                            (1 + n_nbrs) source slabs.
 
 Padding lanes need no masking: pack keeps them zero, every combine here is
 linear in the slab values, and the int8 wire quantizes exact zeros to q = 0
@@ -30,10 +30,13 @@ linear in the slab values, and the int8 wire quantizes exact zeros to q = 0
 of these kernels and later rounds' segment reductions remain exact.
 
 Interpret mode on CPU is bit-compatible with the jnp slab path and is what
-the tier-1 tests pin; on TPU the grid runs compiled.  Use these through the
-``repro.kernels`` (ops.py) wrappers — like every other kernel they default
-to interpret mode there unless ``REPRO_PALLAS_INTERPRET=0`` / an explicit
-``interpret=False`` selects the compiled path.
+the tier-1 tests pin; on TPU the grid runs compiled by Mosaic.  Every block
+is tile-aligned for Mosaic: per-block rows of the static tables arrive as
+``(n_blocks, 1, w)`` arrays whose ``(1, w)`` block spans the array's last two
+dims, and every contraction runs at ``HIGHEST`` precision (the one-hot scale
+matmul must be exact, and the combine must not drop f32 parameter bits to
+the MXU's default bf16 pass).  Use these through the ``repro.kernels``
+(ops.py) wrappers.
 """
 from __future__ import annotations
 
@@ -48,6 +51,7 @@ from repro.kernels.runtime import resolve_interpret
 F32 = jnp.float32
 
 LANES = 128  # column-block width; SlabLayout pads every layer segment to it
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _combine_kernel(a_ref, x_ref, o_ref):
@@ -55,6 +59,7 @@ def _combine_kernel(a_ref, x_ref, o_ref):
     o_ref[...] = jax.lax.dot_general(
         a_ref[0], x_ref[...].astype(F32),
         (((0,), (0,)), ((), ())),
+        precision=HIGHEST,
         preferred_element_type=F32,
     ).astype(o_ref.dtype)
 
@@ -90,13 +95,15 @@ def _dequant_combine_kernel(a_ref, s_ref, seg_ref, q_ref, o_ref):
     # per-column scale via one-hot matmul over the static segment ids —
     # the MXU-friendly spelling of s[:, seg[c]]
     onehot = (
-        seg_ref[0][None, :]
-        == jax.lax.broadcasted_iota(jnp.int32, (n_segs, LANES), 0)
+        seg_ref[...] == jax.lax.broadcasted_iota(jnp.int32, (n_segs, LANES), 0)
     ).astype(F32)
-    s_cols = jnp.dot(s_ref[...], onehot, preferred_element_type=F32)  # (K, 128)
+    s_cols = jnp.dot(
+        s_ref[...], onehot, precision=HIGHEST, preferred_element_type=F32
+    )  # (K, 128)
     deq = s_cols * q_ref[...].astype(F32)
     o_ref[...] = jax.lax.dot_general(
-        a_ref[0], deq, (((0,), (0,)), ((), ())), preferred_element_type=F32
+        a_ref[0], deq, (((0,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=F32,
     )
 
 
@@ -129,48 +136,58 @@ def slab_dequant_combine(
         in_specs=[
             pl.BlockSpec((1, K, K), lambda i: (i, 0, 0)),
             pl.BlockSpec((K, n_segs), lambda i: (0, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, LANES), lambda i: (i, 0, 0)),
             pl.BlockSpec((K, LANES), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((K, LANES), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((K, D), F32),
         interpret=resolve_interpret(interpret),
-    )(A_blocks.astype(F32), scales.astype(F32), col_seg.astype(jnp.int32), q_slab)
+    )(
+        A_blocks.astype(F32),
+        scales.astype(F32),
+        col_seg.astype(jnp.int32).reshape(nb, 1, LANES),
+        q_slab,
+    )
 
 
-def _source_combine_kernel(w_ref, x_ref, o_ref):
-    # out[c] = sum_n w[n] * x[n, c]; w row = this block's layer weights
-    o_ref[...] = jax.lax.dot_general(
-        w_ref[...], x_ref[...].astype(F32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=F32,
-    ).astype(o_ref.dtype)
+def _source_combine_kernel(w_ref, *refs):
+    # out[c] = sum_n w[n] * x_n[c]; w row = this block's layer weights
+    *x_refs, o_ref = refs
+    w = w_ref[...]  # (1, N)
+    out = None
+    for n, x_ref in enumerate(x_refs):
+        term = w[:, n : n + 1] * x_ref[...].astype(F32)
+        out = term if out is None else out + term
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def slab_source_combine(
-    w_blocks: jax.Array, srcs: jax.Array, *, interpret: bool | None = None
+    w_blocks: jax.Array, srcs, *, interpret: bool | None = None
 ):
-    """Per-layer weighted combine over N stacked source slabs in ONE launch
-    (the permute engine's {self} + received-neighbour combine).
+    """Per-layer weighted combine over N source slabs in ONE launch (the
+    permute engine's {self} + received-neighbour combine).
 
     ``w_blocks``: (n_blocks, N) f32 — per column block, the weight of each
     source for that block's layer (``w_all[:, layout.block_layer].T``);
-    ``srcs``: (N, n_blocks*128).  Returns (D,) in the source dtype.
+    ``srcs``: a sequence of N (n_blocks*128,) slabs, read in place (stacking
+    them would copy N slabs, 6 GB at one h2o-danube-3-4b layer's width).
+    Returns (D,) in the first source's dtype.
     """
-    N, D = srcs.shape
+    N = len(srcs)
+    (D,) = srcs[0].shape
     nb = w_blocks.shape[0]
-    if nb * LANES != D:
-        raise ValueError(f"slab width {D} != {nb} blocks x {LANES} lanes")
+    if nb * LANES != D or w_blocks.shape[1] != N:
+        raise ValueError(
+            f"{N} slabs of width {D} vs weights {w_blocks.shape} x {LANES} lanes"
+        )
     out = pl.pallas_call(
         _source_combine_kernel,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, N), lambda i: (i, 0)),
-            pl.BlockSpec((N, LANES), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, LANES), srcs.dtype),
+        in_specs=[pl.BlockSpec((None, 1, N), lambda i: (i, 0, 0))]
+        + [pl.BlockSpec((1, LANES), lambda i: (0, i))] * N,
+        out_specs=pl.BlockSpec((1, LANES), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, D), srcs[0].dtype),
         interpret=resolve_interpret(interpret),
-    )(w_blocks.astype(F32), srcs)
+    )(w_blocks.astype(F32).reshape(nb, 1, N), *[x.reshape(1, D) for x in srcs])
     return out.reshape(D)

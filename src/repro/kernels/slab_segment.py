@@ -32,15 +32,18 @@ kernel serves exact rounds (``dec is self``) and coded rounds (jnp
 encode/decode feeds the kernel; the round's slab-side stats + mixing +
 combine still collapse into this one launch).
 
-TPU caveat: the per-edge gather/scatter (``x[src]``, ``.at[dst].add``) does
-not vectorize on the TPU VPU the way the dense one-hot matmuls do; this
-kernel is the *interpret-mode-validated* structural template for the sparse
-path (tier-1 pins it against the jnp edge path bit-for-bit in interpret
-mode).  On real TPUs the expected lowering is a sort-free segment combine
-over the dst-contiguous edge order — the edge lists arrive (dst, src)-sorted
-precisely so that rewrite stays local to this file.
+Mosaic lowers no row gather or scatter, so every per-edge gather
+(``x[src]``) and per-destination reduction (``.at[dst].add``) in these
+kernels is a one-hot matmul or a masked reduction over (K, E) incidence
+masks: O(K E) per block, cheap at the small in-kernel K.  Compiled, the
+edge-factor algebra runs in that masked spelling
+(:class:`repro.core.drt.EdgeSegments` ``dense=True``); interpreted, it runs
+the jnp path's own gathers and scatters, which keeps interpret mode bit for
+bit on the jnp edge path.  This decoded-slab kernel scatters by a
+(K, E) x (E, lane) matmul.
 
-``slab_edge_encode_combine`` is that rewrite, plus wire residency: instead
+``slab_edge_encode_combine`` is the sort-free rewrite over the
+dst-contiguous edge order, plus wire residency: instead
 of taking a decoded (K, D) f32 slab that a jnp decode pass materialized in
 HBM (~2 extra full-slab passes per coded round — one write, re-read by both
 phases), it takes the COMPACT WIRE itself (int8 quantized values + scales,
@@ -83,42 +86,80 @@ from repro.kernels.runtime import resolve_interpret
 from repro.kernels.slab_codec import _CAST, _scale_cols
 
 F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
 
 LANES = 128
+
+
+def _onehot(idx, n):
+    """(n, E) f32 one-hot of a (1, E) index row: ``[k, e] = (idx[e] == k)``."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, idx.shape[-1]), 0)
+    return (iota == idx).astype(F32)
+
+
+def _take_rows(x, idx):
+    """``x[idx]`` for a (1, E) index row: (K, lane) -> (E, lane) as a one-hot
+    matmul (exact at HIGHEST precision: one unit product per row).  Mosaic
+    lowers no row gather."""
+    return jax.lax.dot_general(
+        _onehot(idx, x.shape[0]), x, (((0,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=F32,
+    )
+
+
+def _row_sq_sums(x):
+    """(N, lane) -> (1, N): each row's sum of squares, as a lane row."""
+    return jnp.sum(jnp.square(x), axis=1)[None]
+
+
+def _pick_cols(row, idx):
+    """``row[0, idx]`` as a column: (1, N) row, (M, 1) index column ->
+    (M, 1), by a masked lane reduction (exact: one nonzero per row)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], row.shape[-1]), 1)
+    return jnp.sum(jnp.where(iota == idx, row, 0.0), axis=1, keepdims=True)
 
 
 def _edge_combine_block(x_self, x_dec, src, dst, a_self, a_e):
     """out[k, c] = a_self[k] x_self[k, c] + sum_{e: dst[e]==k} a_e[e] x_dec[src[e], c].
 
-    Padding edges arrive with ``a_e == 0`` (the weight builders mask on
-    ``w > 0``), so their scatter contribution is an exact zero."""
-    out = x_self * a_self[:, None]
-    gathered = jnp.take(x_dec, src, axis=0) * a_e[:, None]
-    return out.at[dst].add(gathered)
+    ``src``/``dst`` are (1, E) rows and ``a_self`` (1, K) / ``a_e`` (1, E)
+    rows.  Padding edges arrive with ``a_e == 0`` (the weight functions mask
+    on ``w > 0``), so their contribution is an exact zero.  The scatter by
+    destination is a one-hot matmul."""
+    K = x_self.shape[0]
+    ids = jax.lax.broadcasted_iota(jnp.int32, (K, 1), 0)
+    out = x_self * _pick_cols(a_self, ids)
+    weighted = _onehot(dst, K) * a_e  # (K, E): edge weight at its dst row
+    return out + jax.lax.dot_general(
+        weighted, _take_rows(x_dec, src), (((1,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=F32,
+    )
 
 
-def _edge_kernel(algorithm, kappa, N_clip, weight_mode, num_layers, *refs):
+def _edge_kernel(algorithm, kappa, N_clip, weight_mode, num_layers, dense, *refs):
     (bl_ref, self_ref, dec_ref, src_ref, dst_ref, w_ref,
      out_ref, As_ref, Ae_ref, *scratch) = refs
-    src = src_ref[0]
-    dst = dst_ref[0]
-    w = w_ref[0]
+    src = src_ref[...]  # (1, E) rows
+    dst = dst_ref[...]
+    w = w_ref[...]
     K = self_ref.shape[0]
-    p = bl_ref[0]  # this block's DRT layer
+    p = bl_ref[pl.program_id(1)]  # this block's DRT layer
 
     if algorithm == "classical":
         # single phase: weights are a pure function of the edge list — derive
-        # them once at block 0 (the same jnp code as the unkerneled path, so
-        # the factors match bit for bit), combine every block
+        # them once at block 0 (the same jnp code as the unkerneled path, in
+        # its masked-reduction spelling), combine every block
         @pl.when(pl.program_id(1) == 0)
         def _weights():
-            m_self, m_e = metropolis_edge_weights(src, dst, w, K)
-            As_ref[...] = jnp.broadcast_to(m_self[None, :], As_ref.shape)
-            Ae_ref[...] = jnp.broadcast_to(m_e[None, :], Ae_ref.shape)
+            m_self, m_e = metropolis_edge_weights(
+                src[0], dst[0], w[0], K, dense=dense
+            )
+            As_ref[...] = jnp.broadcast_to(m_self, As_ref.shape)
+            Ae_ref[...] = jnp.broadcast_to(m_e, Ae_ref.shape)
 
         out_ref[...] = _edge_combine_block(
             self_ref[...].astype(F32), dec_ref[...].astype(F32),
-            src, dst, As_ref[pl.ds(p, 1)][0], Ae_ref[pl.ds(p, 1)][0],
+            src, dst, As_ref[pl.ds(p, 1)], Ae_ref[pl.ds(p, 1)],
         )
         return
 
@@ -134,22 +175,19 @@ def _edge_kernel(algorithm, kappa, N_clip, weight_mode, num_layers, *refs):
             n2_scr[...] = jnp.zeros_like(n2_scr)
             d2e_scr[...] = jnp.zeros_like(d2e_scr)
 
-        n2_scr[pl.ds(p, 1)] = n2_scr[pl.ds(p, 1)] + jnp.sum(
-            jnp.square(x_dec), axis=1
-        )[None]
-        diff = jnp.take(x_dec, src, axis=0) - jnp.take(x_dec, dst, axis=0)
-        d2e_scr[pl.ds(p, 1)] = d2e_scr[pl.ds(p, 1)] + jnp.sum(
-            jnp.square(diff), axis=1
-        )[None]
+        n2_scr[pl.ds(p, 1)] = n2_scr[pl.ds(p, 1)] + _row_sq_sums(x_dec)
+        diff = _take_rows(x_dec, src) - _take_rows(x_dec, dst)
+        d2e_scr[pl.ds(p, 1)] = d2e_scr[pl.ds(p, 1)] + _row_sq_sums(diff)
 
     @pl.when(jnp.logical_and(ph == 1, i == 0))
     def _mixing():
-        # the SAME edge-factorized eq. 12-14 pipeline as the jnp path, traced
-        # in-kernel on the accumulated stats; the factors land in the
-        # whole-array VMEM-resident outputs which phase-1 blocks read back
+        # the SAME edge-factorized eq. 12-14 pipeline as the jnp path (in
+        # its masked-reduction spelling), traced in-kernel on the
+        # accumulated stats; the factors land in the whole-array
+        # VMEM-resident outputs which phase-1 blocks read back
         cfg = drt_mod.DRTConfig(N=N_clip, kappa=kappa, weight_mode=weight_mode)
         A_self, A_e = drt_mod.drt_edge_mixing(
-            d2e_scr[...], n2_scr[...], src, dst, w, cfg, K
+            d2e_scr[...], n2_scr[...], src[0], dst[0], w[0], cfg, K, dense=dense
         )
         As_ref[...] = A_self
         Ae_ref[...] = A_e
@@ -158,7 +196,7 @@ def _edge_kernel(algorithm, kappa, N_clip, weight_mode, num_layers, *refs):
     def _combine_phase():
         out_ref[...] = _edge_combine_block(
             self_ref[...].astype(F32), x_dec,
-            src, dst, As_ref[pl.ds(p, 1)][0], Ae_ref[pl.ds(p, 1)][0],
+            src, dst, As_ref[pl.ds(p, 1)], Ae_ref[pl.ds(p, 1)],
         )
 
 
@@ -208,7 +246,7 @@ def slab_edge_combine(
     grid = (2, nb) if drt else (1, nb)
 
     in_specs = [
-        pl.BlockSpec((1,), lambda ph, i: (i,), memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),  # whole block->layer map
         pl.BlockSpec((K, lane), lambda ph, i: (0, i)),
         pl.BlockSpec((K, lane), lambda ph, i: (0, i)),
         pl.BlockSpec((1, E), lambda ph, i: (0, 0)),
@@ -232,9 +270,12 @@ def slab_edge_combine(
         jax.ShapeDtypeStruct((num_layers, K), F32),
         jax.ShapeDtypeStruct((num_layers, E), F32),
     )
+    # in-kernel edge algebra: XLA's gathers/scatters where the body runs
+    # interpreted, the masked-reduction spelling Mosaic lowers otherwise
+    interpret = resolve_interpret(interpret)
     kernel = functools.partial(
         _edge_kernel, algorithm, float(kappa), float(N_clip), weight_mode,
-        num_layers,
+        num_layers, not interpret,
     )
     return pl.pallas_call(
         kernel,
@@ -247,7 +288,7 @@ def slab_edge_combine(
             if drt
             else []
         ),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(
         jnp.asarray(block_layer, jnp.int32),
         self_slab.astype(F32),
@@ -276,7 +317,7 @@ def _decode_block(mode, wire_refs):
         # int8 round-trips f32 exactly and the one-hot segment matmul places
         # exactly one unit product per column, so q * s_cols matches the jnp
         # slab_decode bit for bit
-        return q_ref[...].astype(F32) * _scale_cols(s_ref, seg_ref[0])
+        return q_ref[...].astype(F32) * _scale_cols(s_ref, seg_ref[...])
     raise ValueError(f"unknown wire mode {mode!r}")
 
 
@@ -285,15 +326,23 @@ def _csr_combine_block(x_self, dec, nbr, a_self, a_csr):
     from the (dst, src)-sorted edge list, so destination k's edges sit at its
     own CSR row and the combine is Dmax gather-accumulate steps — no
     ``.at[dst].add`` scatter, no serialization hazard.  Padding slots carry
-    ``a_csr == 0`` (masked on ``valid``), an exact zero contribution."""
-    out = x_self * a_self[:, None]
+    ``a_csr == 0`` (masked on ``valid``), an exact zero contribution.
+    ``a_self`` (Kl, 1) and ``a_csr`` (list of Dmax (Kl, 1)) are columns;
+    each neighbour gather is a (Kl, K) one-hot matmul."""
+    out = x_self * a_self
+    K = dec.shape[0]
     for j in range(nbr.shape[1]):
-        out = out + a_csr[:, j][:, None] * jnp.take(dec, nbr[:, j], axis=0)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (nbr.shape[0], K), 1)
+        onehot = (iota == nbr[:, j : j + 1]).astype(F32)  # (Kl, K)
+        gathered = jnp.dot(
+            onehot, dec, precision=HIGHEST, preferred_element_type=F32
+        )
+        out = out + a_csr[j] * gathered
     return out
 
 
 def _edge_encode_kernel(
-    mode, algorithm, kappa, N_clip, weight_mode, num_layers, dmax, *refs
+    mode, algorithm, kappa, N_clip, weight_mode, num_layers, dmax, dense, *refs
 ):
     if algorithm == "drt":
         *head, out_ref, As_ref, Ae_ref, n2_scr, d2e_scr = refs
@@ -304,21 +353,27 @@ def _edge_encode_kernel(
     wire_refs = rest[:-6]
     src_ref, dst_ref, w_ref, nbr_ref, pos_ref, valid_ref = rest[-6:]
 
-    src = src_ref[0]
-    dst = dst_ref[0]
-    w = w_ref[0]
+    src = src_ref[...]  # (1, E) rows
+    dst = dst_ref[...]
+    w = w_ref[...]
     K = wire_refs[0].shape[0]  # TOTAL agents (the wire is everyone's rows)
     Kl = self_ref.shape[0]  # this shard's destination rows
-    p = bl_ref[0]  # this block's DRT layer
+    p = bl_ref[pl.program_id(1)]  # this block's DRT layer
     base = base_ref[0]  # first local destination's global index
 
     def _combine():
         dec = _decode_block(mode, wire_refs)
-        a_self = jax.lax.dynamic_slice_in_dim(As_ref[pl.ds(p, 1)][0], base, Kl)
-        a_e = Ae_ref[pl.ds(p, 1)][0]
-        a_csr = jnp.where(
-            valid_ref[...] != 0, jnp.take(a_e, pos_ref[...], axis=0), 0.0
-        )
+        rows = jax.lax.broadcasted_iota(jnp.int32, (Kl, 1), 0) + base
+        a_self = _pick_cols(As_ref[pl.ds(p, 1)], rows)  # (Kl, 1)
+        a_e = Ae_ref[pl.ds(p, 1)]  # (1, E)
+        pos = pos_ref[...]
+        valid = valid_ref[...]
+        a_csr = [
+            jnp.where(
+                valid[:, j : j + 1] != 0, _pick_cols(a_e, pos[:, j : j + 1]), 0.0
+            )
+            for j in range(dmax)
+        ]
         out_ref[...] = _csr_combine_block(
             self_ref[...].astype(F32), dec, nbr_ref[...], a_self, a_csr
         )
@@ -326,12 +381,14 @@ def _edge_encode_kernel(
     if algorithm == "classical":
         # single phase: the Metropolis factors are D-free edge algebra —
         # derive them once at block 0 (the same jnp code as the unkerneled
-        # path, bit-for-bit factors), combine every block
+        # path, in its masked-reduction spelling), combine every block
         @pl.when(pl.program_id(1) == 0)
         def _weights():
-            m_self, m_e = metropolis_edge_weights(src, dst, w, K)
-            As_ref[...] = jnp.broadcast_to(m_self[None, :], As_ref.shape)
-            Ae_ref[...] = jnp.broadcast_to(m_e[None, :], Ae_ref.shape)
+            m_self, m_e = metropolis_edge_weights(
+                src[0], dst[0], w[0], K, dense=dense
+            )
+            As_ref[...] = jnp.broadcast_to(m_self, As_ref.shape)
+            Ae_ref[...] = jnp.broadcast_to(m_e, Ae_ref.shape)
 
         _combine()
         return
@@ -347,19 +404,15 @@ def _edge_encode_kernel(
             d2e_scr[...] = jnp.zeros_like(d2e_scr)
 
         dec = _decode_block(mode, wire_refs)
-        n2_scr[pl.ds(p, 1)] = n2_scr[pl.ds(p, 1)] + jnp.sum(
-            jnp.square(dec), axis=1
-        )[None]
-        diff = jnp.take(dec, src, axis=0) - jnp.take(dec, dst, axis=0)
-        d2e_scr[pl.ds(p, 1)] = d2e_scr[pl.ds(p, 1)] + jnp.sum(
-            jnp.square(diff), axis=1
-        )[None]
+        n2_scr[pl.ds(p, 1)] = n2_scr[pl.ds(p, 1)] + _row_sq_sums(dec)
+        diff = _take_rows(dec, src) - _take_rows(dec, dst)
+        d2e_scr[pl.ds(p, 1)] = d2e_scr[pl.ds(p, 1)] + _row_sq_sums(diff)
 
     @pl.when(jnp.logical_and(ph == 1, i == 0))
     def _mixing():
         cfg = drt_mod.DRTConfig(N=N_clip, kappa=kappa, weight_mode=weight_mode)
         A_self, A_e = drt_mod.drt_edge_mixing(
-            d2e_scr[...], n2_scr[...], src, dst, w, cfg, K
+            d2e_scr[...], n2_scr[...], src[0], dst[0], w[0], cfg, K, dense=dense
         )
         As_ref[...] = A_self
         Ae_ref[...] = A_e
@@ -430,8 +483,8 @@ def slab_edge_encode_combine(
     grid = (2, nb) if drt else (1, nb)
 
     in_specs = [
-        pl.BlockSpec((1,), lambda ph, i: (i,), memory_space=pltpu.SMEM),
-        pl.BlockSpec((1,), lambda ph, i: (0,), memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),  # whole block->layer map
+        pl.BlockSpec(memory_space=pltpu.SMEM),  # dst_base
         # the self slab is only read by the combine phase: park its window on
         # block 0 through DRT's stats phase (same trick as the output spec)
         # so the round reads the f32 slab ONCE, not once per phase
@@ -452,12 +505,12 @@ def slab_edge_encode_combine(
         in_specs += [
             pl.BlockSpec((K, lane), lambda ph, i: (0, i)),
             pl.BlockSpec((K, n_segs), lambda ph, i: (0, 0)),
-            pl.BlockSpec((1, lane), lambda ph, i: (i, 0)),
+            pl.BlockSpec((None, 1, lane), lambda ph, i: (i, 0, 0)),
         ]
         operands += [
             jnp.asarray(q, jnp.int8),
             scales.astype(F32),
-            jnp.asarray(col_seg, jnp.int32),
+            jnp.asarray(col_seg, jnp.int32).reshape(nb, 1, lane),
         ]
     elif mode in ("exact", "sent") or mode in _CAST:
         (wire,) = wire_operands
@@ -496,9 +549,12 @@ def slab_edge_encode_combine(
         jax.ShapeDtypeStruct((num_layers, K), F32),
         jax.ShapeDtypeStruct((num_layers, E), F32),
     )
+    # in-kernel edge algebra: XLA's gathers/scatters where the body runs
+    # interpreted, the masked-reduction spelling Mosaic lowers otherwise
+    interpret = resolve_interpret(interpret)
     kernel = functools.partial(
         _edge_encode_kernel, mode, algorithm, float(kappa), float(N_clip),
-        weight_mode, num_layers, dmax,
+        weight_mode, num_layers, dmax, not interpret,
     )
     return pl.pallas_call(
         kernel,
@@ -511,5 +567,5 @@ def slab_edge_encode_combine(
             if drt
             else []
         ),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(*operands)

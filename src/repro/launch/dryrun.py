@@ -184,8 +184,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, algorithm: str = "drt",
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax >= 0.4.3x: one dict per device
-            cost = cost[0] if cost else {}
         try:
             mem = compiled.memory_analysis()
             per_dev_mem = getattr(mem, "temp_size_in_bytes", None)

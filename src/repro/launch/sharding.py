@@ -302,7 +302,7 @@ def shard_edge_round(
     unsharded kernel; when K doesn't divide the data axis (or the mesh has
     no data axis) the kernel simply runs replicated.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.kernels import slab_edge_encode_combine
 
@@ -336,7 +336,7 @@ def shard_edge_round(
         out_specs=(specs["out"], specs["A"], specs["A"]),
         # the A outputs are recomputed identically on every shard; shard_map
         # can't prove that, so replication checking is off
-        check_rep=False,
+        check_vma=False,
     )(block_layer, self_slab, wire_operands, src, dst, w, nbr, pos, valid)
 
 

@@ -214,7 +214,7 @@ def make_train_step(
                 "engine already exchanges neighbour-only traffic); use "
                 "consensus_impl='gather' with consensus_path='edge'"
             )
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.core.consensus import PermuteConsensus
@@ -263,7 +263,7 @@ def make_train_step(
                 if obs is None:
                     new = shard_map(
                         body, mesh=mesh, in_specs=(param_specs,),
-                        out_specs=param_specs, check_rep=False,
+                        out_specs=param_specs, check_vma=False,
                     )(params)
                     return new, comm, None
                 # metrics come back as per-agent (K, rounds, ...) stacks:
@@ -271,7 +271,7 @@ def make_train_step(
                 # agent axis, gathered over the data mesh axis
                 new, cm = shard_map(
                     body, mesh=mesh, in_specs=(param_specs,),
-                    out_specs=(param_specs, P("data")), check_rep=False,
+                    out_specs=(param_specs, P("data")), check_vma=False,
                 )(params)
                 return new, comm, cm
 
@@ -305,7 +305,7 @@ def make_train_step(
                         mesh=mesh,
                         in_specs=(param_specs, comm_specs, P()),
                         out_specs=(param_specs, comm_specs),
-                        check_rep=False,
+                        check_vma=False,
                     )(params, comm, ckey)
                     return new, nc, None
                 return shard_map(
@@ -313,7 +313,7 @@ def make_train_step(
                     mesh=mesh,
                     in_specs=(param_specs, comm_specs, P()),
                     out_specs=(param_specs, comm_specs, P("data")),
-                    check_rep=False,
+                    check_vma=False,
                 )(params, comm, ckey)
 
     else:
@@ -504,6 +504,7 @@ def main(argv=None) -> None:
     from repro.data.synthetic import SyntheticTokenStream, TokenStreamConfig
     from repro.models.registry import get_bundle
     from repro.optim import momentum
+    from repro.utils.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(description="decentralized LM training (CPU simulator)")
     ap.add_argument("--arch", default="qwen3-8b-smoke")
@@ -639,6 +640,7 @@ def main(argv=None) -> None:
             "at beta >= 1"
         )
 
+    enable_compile_cache()
     bundle = get_bundle(args.arch, num_agents=args.agents)
     topo = make_topology(args.topology, args.agents)
     opt = momentum(args.lr, 0.9)

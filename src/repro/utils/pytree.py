@@ -75,6 +75,28 @@ def tree_bytes(a: PyTree) -> int:
 # ---------------------------------------------------------------------------
 
 
+# The Gram trick d2 = n2_l + n2_k - 2 G_lk on raw iterates keeps only about
+# eps * (n2_l + n2_k) of absolute precision.  Below this fraction of
+# n2_l + n2_k the raw value may be mostly rounding error (after a local step
+# from a common start it is), and the trick on the iterates less agent 0's,
+# whose error scales with the distances instead, takes its place.
+RAW_D2_MIN_FRACTION = 1e-1
+
+
+def sq_dists_from_grams(raw: jax.Array, centred: jax.Array):
+    """Per-layer ``(d2 (..., K, K), n2 (..., K))`` from the agents' raw
+    Gram matrices and the Gram matrices of their iterates less agent 0's
+    (see ``RAW_D2_MIN_FRACTION``).  Diagonals are read by an identity mask,
+    so the same code runs inside a Pallas kernel."""
+    eye = jnp.eye(raw.shape[-1], dtype=raw.dtype)
+    n2 = jnp.sum(raw * eye, axis=-1)
+    d2 = jnp.maximum(n2[..., :, None] + n2[..., None, :] - 2.0 * raw, 0.0)
+    c2 = jnp.sum(centred * eye, axis=-1)
+    d2c = jnp.maximum(c2[..., :, None] + c2[..., None, :] - 2.0 * centred, 0.0)
+    floor = RAW_D2_MIN_FRACTION * (n2[..., :, None] + n2[..., None, :])
+    return jnp.where(d2 > floor, d2, d2c), n2
+
+
 @dataclasses.dataclass(frozen=True)
 class GroupSpec:
     key: str
@@ -193,7 +215,8 @@ class LayerPartition:
 
         Uses  d2 = n2_k + n2_l - 2 <w_k, w_l>  so the inner product runs on the
         MXU as a (K, D) x (D, K) matmul per group instead of K^2 elementwise
-        differences.
+        differences; where that loses d2 to rounding, the same trick on the
+        iterates less agent 0's (:func:`sq_dists_from_grams`).
 
         Returns ``(d2 (L,K,K), n2 (L,K))``.
         """
@@ -201,35 +224,25 @@ class LayerPartition:
         # (preferred_element_type) — materializing f32 *casts* of the operands
         # would double HBM traffic and force f32 all-gathers for bf16 models
         # (measured: 2.3TB/step f32 copies on kimi-k2; see EXPERIMENTS §Perf).
-        grams = []
+        grams, centred = [], []
         for g in self.groups:
             leaves = jax.tree.leaves(tree_K[g.key])
-            if g.stacked:
-                acc = None
-                for l in leaves:
-                    K, n = l.shape[0], l.shape[1]
-                    flat = l.reshape(K, n, -1)
-                    gm = jnp.einsum(
-                        "knd,jnd->nkj", flat, flat,
-                        preferred_element_type=jnp.float32,
-                    )  # (n, K, K)
-                    acc = gm if acc is None else acc + gm
-                grams.append(acc)
-            else:
-                acc = None
-                for l in leaves:
-                    K = l.shape[0]
-                    flat = l.reshape(K, -1)
-                    gm = jnp.einsum(
-                        "kd,jd->kj", flat, flat, preferred_element_type=jnp.float32
-                    )  # (K, K)
-                    acc = gm if acc is None else acc + gm
-                grams.append(acc[None])
-        gram = jnp.concatenate(grams, axis=0)  # (L, K, K)
-        n2 = jnp.diagonal(gram, axis1=1, axis2=2)  # (L, K)
-        d2 = n2[:, :, None] + n2[:, None, :] - 2.0 * gram
-        d2 = jnp.maximum(d2, 0.0)
-        return d2, n2
+            acc = cacc = None
+            for l in leaves:
+                K = l.shape[0]
+                flat = l.reshape(K, l.shape[1], -1) if g.stacked else l.reshape(K, -1)
+                spec = "knd,jnd->nkj" if g.stacked else "kd,jd->kj"
+                gm = jnp.einsum(spec, flat, flat, preferred_element_type=jnp.float32)
+                # centred in f32: a narrow dtype would round the differences
+                cen = flat.astype(jnp.float32) - flat[:1].astype(jnp.float32)
+                cm = jnp.einsum(spec, cen, cen, preferred_element_type=jnp.float32)
+                acc = gm if acc is None else acc + gm
+                cacc = cm if cacc is None else cacc + cm
+            grams.append(acc if g.stacked else acc[None])  # (n, K, K)
+            centred.append(cacc if g.stacked else cacc[None])
+        return sq_dists_from_grams(
+            jnp.concatenate(grams, axis=0), jnp.concatenate(centred, axis=0)
+        )
 
     # -- per-layer weighted combine ------------------------------------------
 

@@ -36,13 +36,13 @@ def test_permute_consensus_matches_gather_engine():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import ring, hypercube, DRTConfig
         from repro.core.consensus import PermuteConsensus, gather_consensus_step
         from repro.utils.pytree import LayerPartition
 
         K = 8
-        mesh = jax.make_mesh((K,), ("data",))
+        mesh = jax.make_mesh((K,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def tree_init(k):
             k1, k2 = jax.random.split(k)
@@ -98,7 +98,7 @@ def test_sharded_train_step_executes():
         from repro.optim import momentum
 
         K = 4
-        mesh = jax.make_mesh((K, 2), ("data", "model"))
+        mesh = jax.make_mesh((K, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         bundle = get_bundle("qwen3-4b-smoke", num_agents=K)
         opt = momentum(0.05, 0.9)
         step = make_train_step(bundle, ring(K), opt, TrainerConfig(algorithm="drt"))
@@ -138,13 +138,13 @@ def test_engines_agree_under_every_codec():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import ring, hypercube, torus2d, DRTConfig
         from repro.core.consensus import PermuteConsensus, gather_consensus_rounds
         from repro.utils.pytree import LayerPartition
 
         K = 4
-        mesh = jax.make_mesh((K,), ("data",))
+        mesh = jax.make_mesh((K,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def tree_init(k):
             k1, k2 = jax.random.split(k)
@@ -171,7 +171,7 @@ def test_engines_agree_under_every_codec():
                         out, _ = eng(sq, rng=rng, rounds=rounds)
                         return jax.tree.map(lambda x: x[None], out)
                     got = shard_map(body, mesh=mesh, in_specs=(specs,),
-                                    out_specs=specs, check_rep=False)(pK)
+                                    out_specs=specs, check_vma=False)(pK)
                     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
                         np.testing.assert_allclose(
                             np.asarray(a, np.float32), np.asarray(b, np.float32),
@@ -191,14 +191,14 @@ def test_engines_agree_under_dynamic_schedule_every_codec():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import (ring, hypercube, DRTConfig, PeriodicSchedule,
                                 ChurnSchedule)
         from repro.core.consensus import PermuteConsensus, gather_consensus_rounds
         from repro.utils.pytree import LayerPartition
 
         K = 4
-        mesh = jax.make_mesh((K,), ("data",))
+        mesh = jax.make_mesh((K,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def tree_init(k):
             k1, k2 = jax.random.split(k)
@@ -226,7 +226,7 @@ def test_engines_agree_under_dynamic_schedule_every_codec():
                     out, _ = eng(sq, rng=rng, rounds=3, start_round=2)
                     return jax.tree.map(lambda x: x[None], out)
                 got = shard_map(body, mesh=mesh, in_specs=(specs,),
-                                out_specs=specs, check_rep=False)(pK)
+                                out_specs=specs, check_vma=False)(pK)
                 for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
                     np.testing.assert_allclose(
                         np.asarray(a, np.float32), np.asarray(b, np.float32),
@@ -241,7 +241,7 @@ def test_engines_agree_under_dynamic_schedule_every_codec():
             sq = jax.tree.map(lambda x: x[0], local)
             return jax.tree.map(lambda x: x[None], eng(sq, rounds=3, start_round=2))
         got = shard_map(bodyc, mesh=mesh, in_specs=(specs,),
-                        out_specs=specs, check_rep=False)(pK)
+                        out_specs=specs, check_vma=False)(pK)
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-5)
@@ -258,13 +258,13 @@ def test_permute_engine_whole_slab_kernels_match_gather():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import ring, DRTConfig
         from repro.core.consensus import PermuteConsensus, gather_consensus_rounds
         from repro.utils.pytree import LayerPartition
 
         K = 4
-        mesh = jax.make_mesh((K,), ("data",))
+        mesh = jax.make_mesh((K,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def tree_init(k):
             k1, k2 = jax.random.split(k)
@@ -292,7 +292,7 @@ def test_permute_engine_whole_slab_kernels_match_gather():
                     out = eng(sq, rounds=3)
                 return jax.tree.map(lambda x: x[None], out)
             got = shard_map(body, mesh=mesh, in_specs=(specs,),
-                            out_specs=specs, check_rep=False)(pK)
+                            out_specs=specs, check_vma=False)(pK)
             for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                            rtol=2e-4, atol=2e-5,
@@ -375,7 +375,7 @@ def test_permute_telemetry_matches_analytic_and_direct():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import ring, chain, DRTConfig
         from repro.core.consensus import PermuteConsensus
         from repro.comm.accounting import collective_bytes_per_step
@@ -383,7 +383,7 @@ def test_permute_telemetry_matches_analytic_and_direct():
         from repro.utils.pytree import LayerPartition
 
         K = 4
-        mesh = jax.make_mesh((K,), ("data",))
+        mesh = jax.make_mesh((K,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def tree_init(k):
             k1, k2 = jax.random.split(k)
@@ -413,7 +413,7 @@ def test_permute_telemetry_matches_analytic_and_direct():
                                 jax.tree.map(lambda x: x[None], cm))
                     out, cm = shard_map(
                         body, mesh=mesh, in_specs=(specs,),
-                        out_specs=(specs, P("data")), check_rep=False)(pK)
+                        out_specs=(specs, P("data")), check_vma=False)(pK)
                     tag = f"{topo.name}/{codec}/{path}"
                     assert cm.disagreement.shape == (K, 2), tag
                     d = np.asarray(cm.disagreement)
@@ -453,7 +453,7 @@ def test_permute_train_step_threads_codec_state():
         from repro.optim import momentum
 
         K = 4
-        mesh = jax.make_mesh((K, 2), ("data", "model"))
+        mesh = jax.make_mesh((K, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         bundle = get_bundle("qwen3-4b-smoke", num_agents=K)
         opt = momentum(0.05, 0.9)
         codec = "topk:0.1"
@@ -487,14 +487,14 @@ def test_permute_consensus_control():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import ring, DRTConfig
         from repro.core.consensus import PermuteConsensus
         from repro.obs.metrics import ObsConfig
         from repro.utils.pytree import LayerPartition
 
         K = 8
-        mesh = jax.make_mesh((K,), ("data",))
+        mesh = jax.make_mesh((K,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
         def tree_init(k):
             k1, k2 = jax.random.split(k)
@@ -524,7 +524,7 @@ def test_permute_consensus_control():
                         jax.tree.map(lambda x: x[None], cm))
             out_specs = spec if obs is None else (spec, P("data"))
             return shard_map(body, mesh=mesh, in_specs=(spec,),
-                             out_specs=out_specs, check_rep=False)(pK)
+                             out_specs=out_specs, check_vma=False)(pK)
 
         base = PermuteConsensus(part, topo, DRTConfig(), axis_name="data")
         zero = PermuteConsensus(part, topo, DRTConfig(), axis_name="data",

@@ -391,3 +391,34 @@ def test_graph_stats_flop_and_byte_ratios_hand_computed():
     assert s_bf16["dense_vs_edge_byte_ratio"] == pytest.approx(1.0)
     s_f32 = schedule_graph_stats(StaticSchedule(er), wire_itemsize=4)
     assert s_f32["dense_vs_edge_byte_ratio"] == pytest.approx(0.75)
+
+
+# ---------------------------------------------------------------------------
+# in-kernel (masked-reduction) spelling of the edge-factor algebra
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("graph", ["ring", "erdos_renyi"])
+def test_dense_edge_segments_match_scatter_spelling(graph):
+    """The one-hot / masked-reduction spelling the compiled edge kernels run
+    (``dense=True``) gives the scatter path's edge factors to a few f32 ulps
+    (gathers, mins and maxes agree exactly; sums and fused elementwise
+    chains may round in another order), at ring and ER in-degrees."""
+    from repro.core.drt import DRTConfig, drt_edge_mixing
+    from repro.core.dynamic import edge_stacks_from_topology, metropolis_edge_weights
+
+    K64, L = 32, 5
+    topo = (ring(K64) if graph == "ring"
+            else make_topology("erdos_renyi", K64, p=0.15, seed=2))
+    e = edge_stacks_from_topology(topo, 1)
+    src, dst, w = e.src[0], e.dst[0], e.w[0]
+    k1, k2 = jax.random.split(jax.random.key(4))
+    d2 = jax.random.uniform(k1, (L, src.shape[0])) * 3.0
+    n2 = jax.random.uniform(k2, (L, K64)) * 5.0 + 1.0
+    for dense_fn in (
+        lambda dense: drt_edge_mixing(d2, n2, src, dst, w, DRTConfig(), K64, dense),
+        lambda dense: metropolis_edge_weights(src, dst, w, K64, dense),
+    ):
+        for a, b in zip(jax.jit(dense_fn, static_argnums=0)(False),
+                        jax.jit(dense_fn, static_argnums=0)(True)):
+            assert float(jnp.max(jnp.abs(a - b))) <= 1e-6  # factors are <= 1

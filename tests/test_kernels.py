@@ -229,7 +229,7 @@ def test_slab_source_combine_matches_jnp():
     srcs = jax.random.normal(jax.random.key(0), (N, layout.D))
     w = jax.random.uniform(jax.random.key(1), (N, layout.num_layers))
     w_blocks = jnp.take(w, jnp.asarray(layout.block_layer), axis=1).T
-    got = slab_source_combine(w_blocks, srcs)
+    got = slab_source_combine(w_blocks, list(srcs))
     want = jnp.einsum(
         "nc,nc->c", jnp.take(w, jnp.asarray(layout.block_layer), axis=1
         ).repeat(layout.lane, axis=1), srcs

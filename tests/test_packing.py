@@ -767,3 +767,15 @@ def test_layout_col_maps_cover_every_column():
                 cols = np.arange(base + plan.col0, base + plan.col0 + plan.width)
                 got = flat[layout.col_leaf[cols[0]]][layout.col_idx[cols]]
                 np.testing.assert_array_equal(slab[cols], got.astype(np.float32))
+
+
+def test_col_maps_match_static_tables():
+    """``SlabLayout.col_maps`` (built from iotas on device, no D-sized
+    constants) equals the static numpy column tables the kernels were
+    specified against."""
+    pK = _tree_K(4)
+    _, layout = _layout_for(pK)
+    seg, leaf, idx = (np.asarray(m).reshape(-1) for m in jax.jit(layout.col_maps)())
+    np.testing.assert_array_equal(seg, layout.col_scale_seg)
+    np.testing.assert_array_equal(leaf, layout.col_leaf)
+    np.testing.assert_array_equal(idx, layout.col_idx)
