@@ -15,8 +15,8 @@ PR 4 adds the *orchestration* metrics around the rounds:
                      rounds=8, scanned (lax.scan, O(1) in rounds) vs the
                      unrolled parity oracle (O(rounds)).
   dispatch           static Pallas-launch counts per round-set with
-                     use_kernels=True (whole-slab batched kernels: ONE
-                     launch per coded round, one per exact round-set).
+                     use_kernels=True (batched kernels: ONE launch per
+                     coded round, one per group per exact round-set).
   train_many_steps   steps/s of the donated multi-step driver
                      (``make_many_steps`` scanning local-step + consensus)
                      vs per-step jitted dispatch at 8 steps/call.
@@ -835,8 +835,8 @@ def run_byzantine(
 def run_dispatch_counts(K: int = 16, rounds: int = ROUNDS):
     """Static Pallas-launch counts of one ``use_kernels=True`` round-set:
     the whole-slab batched kernels issue ONE launch per coded round (and one
-    per round-SET on the exact Gram path), independent of the model's
-    (groups x slots) layer count."""
+    per group per round-SET on the exact Gram path), independent of the
+    round count and of the slots in each group."""
     from repro.utils.dispatch import count_pallas_launches
 
     pK = _model_stack(jax.random.key(0), K)

@@ -46,8 +46,9 @@ bit-parity oracle.
 ``repro.kernels``: every CODED round is ONE ``slab_encode_combine`` launch
 (in-kernel RNG + scale reconstruction + per-layer Gram accumulation +
 in-kernel DRT mixing math + combine + full-precision self term — the wire
-and decoded slabs never hit HBM), the exact path keeps its one
-``slab_combine`` launch per round-SET, and the permute engine uses
+and decoded slabs never hit HBM), the exact path makes one
+``slab_combine`` launch per group per round-SET, on the regions as they
+are, and the permute engine uses
 ``slab_quant_encode`` / ``slab_source_combine`` with ``drt_dist`` for its
 neighbour statistics; on CPU they execute in interpret mode and are
 parity-tested against the jnp slab path and the per-slot kernel references.
@@ -344,18 +345,16 @@ def _slab_mixing(layout, regions_f32, C, cfg, algorithm, metropolis, num_layers)
 
 
 def _combine_slab_kernels(layout, A, regions):
-    """Kernel-backed whole-slab combine: ONE grid-based ``slab_combine``
-    launch over the packed (K, D) slab per call.  The per-block (K, K)
-    mixing matrices are gathered from the static ``layout.block_layer`` map
-    (layer segments are lane-padded, so blocks never straddle layers).
-    Interpret mode on CPU."""
+    """Kernel-backed per-layer combine: one ``slab_combine`` launch per
+    group, on the group's region as it is, with that group's mixing
+    matrices ``A[layer0 : layer0 + n_slots]``; no joined slab.  Interpret
+    mode on CPU."""
     from repro.kernels import slab_combine
 
-    A_blocks = jnp.take(
-        A.astype(jnp.float32), jnp.asarray(layout.block_layer), axis=0
+    return tuple(
+        slab_combine(A[grp.layer0 : grp.layer0 + grp.n_slots], region)
+        for grp, region in zip(layout.groups, regions)
     )
-    out = slab_combine(A_blocks, layout.join(regions))
-    return layout.split(out)
 
 
 def _dequant_combine_slab_kernels(layout, A_off, wire):

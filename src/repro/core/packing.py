@@ -187,10 +187,12 @@ class SlabLayout:
     def block_layer(self) -> np.ndarray:
         """(D // lane,) int32: the DRT layer owning each lane-wide column
         block.  Layer segments are lane-padded, so a block never straddles a
-        layer boundary — the whole-slab batched combine kernels
-        (:mod:`repro.kernels.slab_combine`) gather one (K, K) mixing matrix
-        per block from this map and stream the packed (K, D) slab through a
-        single grid instead of one launch per (group, slot)."""
+        layer boundary — the whole-slab kernels that stream the packed
+        (K, D) slab through one grid of 128-lane blocks
+        (``slab_dequant_combine``, ``slab_source_combine``, and the edge and
+        codec kernels) read one layer per block from this map.  The exact
+        ``slab_combine`` does not: it runs per group on the regions, whose
+        slots are one layer each."""
         out = np.empty(self.n_blocks, np.int32)
         for p, (s, e) in enumerate(self.layer_slices):
             out[s // self.lane : e // self.lane] = p
@@ -303,6 +305,10 @@ class SlabLayout:
                     f"layout expects {(*batch, *plan.shape)}"
                 )
             n = grp.n_slots if grp.stacked else 1
+            if n == 1:
+                piece = _unit_free_reshape(arr.astype(self.dtype), (*batch, plan.width))
+                parts.append(piece[None])
+                continue
             piece = arr.astype(self.dtype).reshape(*batch, n, plan.width)
             parts.append(jnp.moveaxis(piece, -2, 0))  # (n, *batch, width)
         pad = grp.s_pad - grp.s
@@ -332,7 +338,10 @@ class SlabLayout:
                 piece = jax.lax.slice_in_dim(
                     region, plan.col0, plan.col0 + plan.width, axis=-1
                 )  # (n, *batch, width)
-                piece = jnp.moveaxis(piece, 0, -2)  # (*batch, n, width)
+                if grp.n_slots == 1:
+                    piece = _unit_free_reshape(piece[0], (*batch, *plan.shape))
+                else:
+                    piece = jnp.moveaxis(piece, 0, -2)  # (*batch, n, width)
                 new_leaves[plan.local_idx] = piece.reshape(
                     *batch, *plan.shape
                 ).astype(dtype if dtype is not None else plan.dtype)
@@ -595,6 +604,24 @@ class SlabLayout:
             )  # (*batch, n)
             out.append(region * jnp.moveaxis(w, -1, 0)[..., None])
         return tuple(out)
+
+
+def _unit_free_reshape(x: jax.Array, shape: tuple) -> jax.Array:
+    """``x.reshape(shape)`` between one-slot leaves and regions, relaid out
+    between the two shapes with their size-1 axes dropped.  On TPU, XLA
+    lays a leaf out into another tiling with one fused copy, but lowers
+    the same relayout as a reshape whose generated code grows with the
+    array where a size-1 axis sits on either side (127 MB for one
+    h2o-danube-3-4b embedding at K=2, 186 MB for its layer's group); a
+    loaded program's code stays in HBM, where ``peak_hbm_gib`` counts it.
+    The barriers keep XLA from folding the size-1 axes back into the
+    relayout."""
+    core = tuple(d for d in shape if d != 1)
+    if core == tuple(d for d in x.shape if d != 1):
+        return x.reshape(shape)
+    barrier = jax.lax.optimization_barrier
+    x = barrier(x.reshape(tuple(d for d in x.shape if d != 1)))
+    return barrier(x.reshape(core)).reshape(shape)
 
 
 def gram_sq_dists(gram: jax.Array) -> tuple[jax.Array, jax.Array]:

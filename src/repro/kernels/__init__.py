@@ -6,7 +6,7 @@ mode on CPU; see EXPERIMENTS.md §Perf for the HBM-traffic math per kernel).
   int8_quantize   fused scale + stochastic round for the int8 wire codec
   int8_dequantize q * s -> f32
   dequant_combine fused dequantize + weighted combine over int8 neighbours
-  slab_combine    whole-slab per-layer mixing: ONE grid launch per round
+  slab_combine    per-layer mixing of a group's region: ONE launch per group
   slab_dequant_combine  whole-slab fused int8 dequant+combine, one launch
   slab_source_combine   whole-slab {self}+neighbour combine (permute engine)
   slab_encode_combine   a WHOLE coded round (encode + Gram + DRT mixing +

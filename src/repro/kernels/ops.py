@@ -58,10 +58,10 @@ def dequant_combine(a, scales, qs, *, interpret: bool | None = None):
     )
 
 
-def slab_combine(A_blocks, slab, *, interpret: bool | None = None):
-    """Whole-slab per-layer agent mixing in ONE grid launch."""
+def slab_combine(A, region, *, interpret: bool | None = None):
+    """Per-layer agent mixing of one group's region in ONE grid launch."""
     return _slab_combine(
-        A_blocks, slab, interpret=interpret
+        A, region, interpret=interpret
     )
 
 

@@ -2,7 +2,10 @@
 
 Each kernel is lowered with ``interpret=False`` and compiled for a described
 (not attached) ``v5e:2x2`` topology at the paper job's widths: K=16 agents,
-the ResNet-20 (width 16) slab of D=309,248 columns, ring edges.  Nothing
+the ResNet-20 (width 16) slab of D=309,248 columns, ring edges; and the
+exact combine once more at one h2o-danube-3-4b layer's width (K=2, one
+region of 154,836,480 columns), where its tile is widest, with four K=2
+slots to a block, and at K=64, the most agents the exact path serves.  Nothing
 runs; a compile that passes proves Mosaic accepts the block shapes, the
 in-kernel ops and the VMEM budget, and ``tpu_custom_call`` in the compiled
 text proves a Mosaic kernel (not the interpreter) is in the program.
@@ -101,9 +104,21 @@ def _cases(p):
     nbr, pos, valid = p["csr"]
     slab, q, bl, mix = s((K, D)), s((K, D), I8), s((nb,), I32), s((K, K))
     return {
-        "slab_combine": (
+        "slab_combine": (  # the widest region: stage 3, 3 slots of 78,080
             lambda a, x: kk.slab_combine(a, x, interpret=False),
-            (s((nb, K, K)), slab),
+            (s((3, K, K)), s((3, K, 78080))),
+        ),
+        "slab_combine_danube": (  # one h2o-danube-3-4b layer's region, K=2
+            lambda a, x: kk.slab_combine(a, x, interpret=False),
+            (s((1, 2, 2)), s((1, 2, 154_836_480))),
+        ),
+        "slab_combine_k2_stacked": (  # four K=2 slots to one (8, tile) block
+            lambda a, x: kk.slab_combine(a, x, interpret=False),
+            (s((4, 2, 2)), s((4, 2, 78080))),
+        ),
+        "slab_combine_k64": (  # the widest region at K=64, one slot a block
+            lambda a, x: kk.slab_combine(a, x, interpret=False),
+            (s((3, 64, 64)), s((3, 64, 78080))),
         ),
         "slab_dequant_combine": (
             lambda a, sc, cs, qq: kk.slab_dequant_combine(a, sc, cs, qq, interpret=False),
@@ -155,6 +170,9 @@ def _cases(p):
     "name",
     [
         "slab_combine",
+        "slab_combine_danube",
+        "slab_combine_k2_stacked",
+        "slab_combine_k64",
         "slab_dequant_combine",
         "slab_source_combine",
         "slab_quant_encode",

@@ -167,13 +167,50 @@ def _region_err(a, b):
     )
 
 
-def test_slab_combine_matches_per_slot_kernel_reference():
-    """The ONE-launch whole-slab combine reproduces PR 2's per-(group, slot)
-    kernel loop (interpret mode) — and both match the jnp slab combine."""
+def _wide_slab_setup(K, key=jax.random.key(0)):
+    """A layout whose ``head`` layer is narrower than one ``slab_combine``
+    tile (1280 columns: one 1024-column step of the tile loop and a
+    remainder) and whose four ``blocks`` layers are each wider than one tile
+    and no multiple of it, so the grid runs a partial last tile in every
+    slot.  The ``blocks`` region's rows split into blocks of one slot
+    (K=16), two slots in each of two blocks (K=4), or all four slots in one
+    block (K=2)."""
+    from repro.core import build_slab_layout
+    from repro.kernels.slab_combine import block_rows, tile_cols
+    from repro.utils.pytree import LayerPartition
+
+    n = 4
+    rows = block_rows(n, K)
+    width = tile_cols(rows, 1 << 30, jnp.float32) + 700
+
+    def one(k):
+        ks = jax.random.split(k, 2)
+        return {
+            "head": {"w": jax.random.normal(ks[0], (1200,))},
+            "blocks": {"w": jax.random.normal(ks[1], (n, width))},
+        }
+
+    pK = jax.vmap(one)(jax.random.split(key, K))
+    template = jax.tree.map(lambda x: x[0], pK)
+    part = LayerPartition.build(template)
+    layout = build_slab_layout(part, template)
+    blocks, head = layout.groups  # top-level keys in sorted order
+    assert blocks.n_slots == n and head.n_slots == 1
+    assert tile_cols(block_rows(1, K), head.s_pad, jnp.float32) == head.s_pad
+    tile = tile_cols(rows, blocks.s_pad, jnp.float32)
+    assert tile < blocks.s_pad and blocks.s_pad % tile
+    return pK, part, layout
+
+
+@pytest.mark.parametrize("K", [2, 4, 16])
+def test_slab_combine_matches_per_slot_kernel_reference(K):
+    """The per-group wide-tile combine reproduces the per-(group, slot)
+    ``weighted_combine`` loop (interpret mode) — and both match the jnp slab
+    combine — with layers narrower than one tile and wider than one tile,
+    and with one, two or four slots to a block."""
     from repro.core.consensus import _combine_slab_kernels, _combine_slab_per_slot
 
-    K = 4
-    pK, part, layout = _slab_setup(K)
+    pK, part, layout = _wide_slab_setup(K)
     regions = layout.pack_regions(pK)
     A = jax.random.dirichlet(
         jax.random.key(3), jnp.ones(K), (part.num_layers, K)
@@ -241,9 +278,10 @@ def test_use_kernels_issues_one_pallas_launch_per_round():
     """The acceptance probe: with use_kernels=True the gather round-set
     issues O(1) Pallas launches per round — exactly ONE ``slab_encode_combine``
     per coded round (encode, stats, mixing, combine AND the self term all in
-    that one launch, for EVERY codec incl. top-k), and 1 per round-SET on the
-    exact Gram path — independent of the model's (groups x slots) count.  The
-    per-slot reference pays one per segment."""
+    that one launch, for EVERY codec incl. top-k), and one per group per
+    round-SET on the exact Gram path — independent of the round count and
+    of the slots in each group.  The per-slot reference pays one per
+    segment."""
     from repro.core import DRTConfig, gather_consensus_rounds, ring
     from repro.core.consensus import _combine_slab_per_slot
     from repro.utils.dispatch import count_pallas_launches
@@ -252,7 +290,7 @@ def test_use_kernels_issues_one_pallas_launch_per_round():
     pK, part, layout = _slab_setup(K)
     C = jnp.asarray(ring(K).c_matrix(), jnp.float32)
     n_segments = sum(g.n_slots for g in layout.groups)
-    assert n_segments > 1  # the claim is non-trivial for this model
+    assert n_segments > len(layout.groups) > 1  # non-trivial for this model
 
     for rounds in (3, 8):
         for codec, per_round in (
@@ -267,7 +305,8 @@ def test_use_kernels_issues_one_pallas_launch_per_round():
                 pK,
             )
             if codec is None:
-                assert n == 1, (codec, rounds, n)  # one combine per round-SET
+                # one combine per group per round-SET
+                assert n == len(layout.groups), (codec, rounds, n)
             else:
                 assert n == per_round * rounds, (codec, rounds, n)
 
