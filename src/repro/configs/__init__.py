@@ -5,6 +5,7 @@ from repro.configs import (  # noqa: F401
     gemma3_27b,
     h2o_danube_3_4b,
     hymba_1_5b,
+    kanana_2_30b_a3b,
     kimi_k2_1t_a32b,
     llama4_maverick_400b_a17b,
     llava_next_34b,
@@ -34,4 +35,5 @@ ASSIGNED_ARCHS = (
     "falcon-mamba-7b",
     "qwen3-4b",
     "gemma3-27b",
+    "kanana-2-30b-a3b",
 )

@@ -2,6 +2,12 @@
 vocab=163840, MoE 384 experts top-8 — trillion-parameter MoE (paper-table).
 [arXiv:2501.kimi2]
 
+The attention here is a GQA stand-in: the published model has the
+DeepSeek-V3 architecture, with multi-head latent attention (kv_lora_rank
+512, q_lora_rank 1536).  ``models/mla.py`` has MLA for training
+(kanana-2-30b-a3b runs it), but converting this config needs an MLA decode
+cache for its serving paths, which does not exist.
+
 d_ff=2048 is the per-expert intermediate width (DeepSeek-V3-style narrow
 experts); one shared expert of the same width.  Total ~1.03T params, ~32B
 active.  Decentralized-training capacity note (DESIGN.md §4): a 1T model

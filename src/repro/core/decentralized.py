@@ -219,20 +219,27 @@ class DecentralizedTrainer:
     # -- step functions (pure; jit/vmap-friendly) ------------------------------
 
     def local_step(self, state: DecentralizedState, batch_K, rng: jax.Array):
-        """One local SGD step per agent (eq. 3a / first line of (11))."""
+        """One local SGD step per agent (eq. 3a / first line of (11)).
+
+        A loss marked ``has_aux`` (``repro.models.registry.with_stats``)
+        returns ``(loss, stats)``; the metrics then carry the per-agent
+        stats under ``"stats"``."""
         keys = jax.random.split(rng, self.K)
+        has_aux = getattr(self.loss_fn, "has_aux", False)
 
         def one(params, batch, key):
-            return jax.value_and_grad(self.loss_fn)(params, batch, key)
+            return jax.value_and_grad(self.loss_fn, has_aux=has_aux)(params, batch, key)
 
-        losses, grads = jax.vmap(one)(state.params, batch_K, keys)
+        out, grads = jax.vmap(one)(state.params, batch_K, keys)
+        losses, stats = out if has_aux else (out, None)
         new_params, new_opt = self.optimizer.update(
             grads, state.opt_state, state.params, state.step
         )
-        return (
-            DecentralizedState(new_params, new_opt, state.step + 1, state.comm),
-            {"loss": jnp.mean(losses)},
-        )
+        state = DecentralizedState(new_params, new_opt, state.step + 1, state.comm)
+        metrics = {"loss": jnp.mean(losses)}
+        if has_aux:
+            metrics["stats"] = stats
+        return state, metrics
 
     def consensus(
         self,

@@ -22,6 +22,9 @@ mode on CPU; see EXPERIMENTS.md §Perf for the HBM-traffic math per kernel).
   slab_cast_combine     bf16/f16 cast-combine round, wire never in HBM
   selective_scan  chunked Mamba-1 recurrence, VMEM-carried state
   flash_attention online-softmax attention, VMEM score tiles
+  moe_gmm         grouped matmuls over the experts a chip holds, with a
+                  custom VJP (``repro.kernels.moe_gmm``; used by
+                  ``models.moe``)
 """
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention

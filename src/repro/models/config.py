@@ -30,14 +30,39 @@ class AttnCfg:
 
 
 @dataclasses.dataclass(frozen=True)
+class MLACfg:
+    """Multi-head latent attention (DeepSeek-V2/V3) without query
+    compression: per-head queries of ``qk_nope_head_dim + qk_rope_head_dim``,
+    keys and values from a ``kv_lora_rank`` latent plus one shared RoPE key,
+    values of ``v_head_dim``."""
+
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 1e6
+
+
+@dataclasses.dataclass(frozen=True)
 class MoECfg:
-    n_experts: int
+    n_experts: int  # the router's width
     top_k: int
     d_ff_expert: int
     shared_d_ff: int = 0  # 0 = no shared expert
     capacity_factor: float = 1.25
     group_size: int = 4096  # tokens per dispatch group
     aux_loss_weight: float = 0.01
+    # the expert-share layer ("mla_moe"): the routed weights' scale, and the
+    # experts this layer holds, [held_offset, held_offset + n_held) (None:
+    # all of them)
+    routed_scale: float = 1.0
+    n_held: int | None = None
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,7 +78,9 @@ class SSMCfg:
 
 @dataclasses.dataclass(frozen=True)
 class LayerCfg:
-    kind: Literal["attn_mlp", "moe", "mamba", "hymba"] = "attn_mlp"
+    # "mla_mlp" / "mla_moe": latent attention with a dense MLP / with the
+    # expert-share layer (models.moe.expert_share_apply)
+    kind: Literal["attn_mlp", "moe", "mamba", "hymba", "mla_mlp", "mla_moe"] = "attn_mlp"
     window: int | None = None  # sliding-window size; None = full attention
 
 
@@ -89,6 +116,7 @@ class ModelConfig:
     d_ff: int
     groups: tuple[GroupCfg, ...]
     attn: AttnCfg | None = None
+    mla: MLACfg | None = None
     moe: MoECfg | None = None
     ssm: SSMCfg | None = None
     encoder: EncoderCfg | None = None  # audio (enc-dec) only
@@ -98,6 +126,10 @@ class ModelConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     remat: bool = True
+    # positions per block of the output head and cross-entropy, each block
+    # rematerialised, so that no (B, S, vocab) logits exist at once (long
+    # sequences); None: the whole sequence at once
+    head_chunk: int | None = None
     # decentralized-training defaults for this arch (see DESIGN.md §4)
     num_agents: int = 16
     expert_axis: str | None = "model"  # mesh axis for the expert dim of MoE weights
@@ -148,8 +180,12 @@ class ModelConfig:
         n_moe_layers = sum(
             g.repeat * sum(1 for lc in g.unit if lc.kind == "moe") for g in self.groups
         )
+        n_share_layers = sum(
+            g.repeat * sum(1 for lc in g.unit if lc.kind == "mla_moe") for g in self.groups
+        )
         per_expert = 3 * self.d_model * m.d_ff_expert
         total -= n_moe_layers * (m.n_experts - m.top_k) * per_expert
+        total -= n_share_layers * (m.held - m.top_k) * per_expert
         return total
 
     def _layer_params(self, lc: LayerCfg) -> int:
@@ -163,6 +199,20 @@ class ModelConfig:
                 + (2 * a.head_dim if a.qk_norm else 0)
             )
         mlp_n = 3 * d * self.d_ff
+        if lc.kind in ("mla_mlp", "mla_moe"):
+            a = self.mla
+            H, r, rope = a.n_heads, a.kv_lora_rank, a.qk_rope_head_dim
+            mla_n = (
+                d * H * (a.qk_nope_head_dim + rope)  # wq
+                + d * (r + rope)  # wkv_a
+                + r  # kv_norm
+                + r * H * (a.qk_nope_head_dim + a.v_head_dim)  # wkv_b
+                + H * a.v_head_dim * d  # wo
+            )
+            if lc.kind == "mla_mlp":
+                return 2 * d + mla_n + mlp_n
+            m = self.moe
+            return 2 * d + mla_n + d * m.n_experts + m.held * 3 * d * m.d_ff_expert + 3 * d * m.shared_d_ff
         if lc.kind == "attn_mlp":
             return 2 * d + attn_n + mlp_n
         if lc.kind == "moe":

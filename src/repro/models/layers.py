@@ -8,9 +8,13 @@ Conventions
 * ``compute_dtype`` (usually bf16) applies to activations/matmuls; norms,
   softmax and rope run in f32.
 * attention is *flash-style* (never materializes the (S, S) score matrix):
-  full-causal attention scans over KV chunks with a running max/denominator;
-  sliding-window attention scans over Q chunks and dynamic-slices only the
-  in-window KV span — its FLOPs scale with S x window, not S^2.
+  causal self-attention runs over square blocks below the diagonal with a
+  running max/denominator, and its custom VJP keeps only the output and the
+  log-sum-exp, recomputing the blocks (FlashAttention-2); other full
+  attention scans over KV chunks; sliding-window attention scans over Q
+  chunks and dynamic-slices only the in-window KV span — its FLOPs scale
+  with S x window, not S^2.  The value head width may differ from the
+  query/key width (latent attention: 192 and 128).
 """
 from __future__ import annotations
 
@@ -140,14 +144,17 @@ def flash_attention(
     kv_chunk: int = 1024,
     q_chunk: int = 512,
 ):
-    """Chunked attention.  q: (B,Sq,H,hd), k/v: (B,Skv,Hkv,hd).
+    """Chunked attention.  q: (B,Sq,H,hd), k: (B,Skv,Hkv,hd), v:
+    (B,Skv,Hkv,dv); returns (B,Sq,H,dv).
 
     ``window``: sliding-window size (keys in (i-window, i] attend); None =
     full causal.  ``q_offset``: absolute position of q[0] (for decode /
-    chunked prefill).  Softmax statistics in f32.
+    chunked prefill).  Softmax statistics in f32.  Causal self-attention
+    over a whole sequence goes by blocks of ``q_chunk`` (:func:`causal_attention`).
     """
     B, Sq, H, hd = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     n_rep = H // Hkv
     k = _gqa_expand(k, n_rep)
     v = _gqa_expand(v, n_rep)
@@ -155,6 +162,8 @@ def flash_attention(
 
     if window is not None and Sq > 1:
         return _windowed_attention(q, k, v, window, q_offset, q_chunk, scale)
+    if causal and window is None and q_offset == 0 and Sq == Skv and Sq > 1 and not _UNROLL_INNER:
+        return causal_attention(q, k, v, block=min(q_chunk, Sq))
 
     kv_chunk = min(kv_chunk, Skv)
     n_kv = -(-Skv // kv_chunk)
@@ -195,7 +204,7 @@ def flash_attention(
 
     m0 = jnp.full((B, H, Sq), -1e30, F32)
     l0 = jnp.zeros((B, H, Sq), F32)
-    acc0 = jnp.zeros((B, H, Sq, hd), F32)
+    acc0 = jnp.zeros((B, H, Sq, dv), F32)
     if _UNROLL_INNER:
         carry = (m0, l0, acc0)
         for idx in range(n_kv):
@@ -256,8 +265,138 @@ def _windowed_attention(q, k, v, window, q_offset, q_chunk, scale):
         outs = jnp.stack([one_chunk(jnp.asarray(i)) for i in range(n_q)])
     else:
         outs = jax.lax.map(one_chunk, jnp.arange(n_q))  # (n_q, B, q_chunk, H, hd)
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, n_q * q_chunk, H, hd)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, n_q * q_chunk, H, v.shape[-1])
     return out[:, :Sq].astype(q.dtype)
+
+
+def causal_attention(q, k, v, *, block: int = 512):
+    """Causal self-attention by square blocks on and below the diagonal.
+    q/k: (B,S,H,hd), v: (B,S,H,dv) -> (B,S,H,dv), scale 1/sqrt(hd).
+
+    A row of query blocks runs over the key blocks up to its own, so the
+    blocks above the diagonal cost nothing.  The custom VJP keeps the inputs,
+    the output and each row's log-sum-exp and recomputes the blocks; no
+    (S, S) array exists in either direction.  Products take the operands'
+    dtype with f32 accumulation."""
+    S = q.shape[1]
+    pad = -S % block
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0))) for t in (q, k, v))
+    qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # (B,H,Sp,·)
+    out = _causal_blocks(qt, kt, vt, block)
+    return out.transpose(0, 2, 1, 3)[:, :S]
+
+
+def _block(x, i, block):
+    return jax.lax.dynamic_slice_in_dim(x, i * block, block, axis=2)
+
+
+def _block_scores(q_i, k_j, i, j, block):
+    """Scaled scores of query block i against key block j, masked above
+    the diagonal."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q_i, k_j, preferred_element_type=F32)
+    s = s * (1.0 / np.sqrt(q_i.shape[-1]))
+    row = i * block + jnp.arange(block)[:, None]
+    col = j * block + jnp.arange(block)[None, :]
+    return jnp.where(col <= row, s, -1e30)
+
+
+def _causal_forward(q, k, v, block):
+    B, H, Sp, _ = q.shape
+    dv = v.shape[-1]
+
+    def row(i):
+        q_i = _block(q, i, block)
+
+        def step(j, carry):
+            m, l, acc = carry
+            s = _block_scores(q_i, _block(k, j, block), i, j, block)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bhqk,bhkd->bhqd", p.astype(v.dtype), _block(v, j, block),
+                preferred_element_type=F32,
+            )
+            return m_new, l * corr + jnp.sum(p, axis=-1), acc
+
+        init = (
+            jnp.full((B, H, block), -1e30, F32),
+            jnp.zeros((B, H, block), F32),
+            jnp.zeros((B, H, block, dv), F32),
+        )
+        m, l, acc = jax.lax.fori_loop(0, i + 1, step, init)
+        return (acc / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    o, lse = jax.lax.map(row, jnp.arange(Sp // block))  # (n, B, H, block, ·)
+    o = jnp.moveaxis(o, 0, 2).reshape(B, H, Sp, dv)
+    return o, jnp.moveaxis(lse, 0, 2).reshape(B, H, Sp)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _causal_blocks(q, k, v, block):
+    return _causal_forward(q, k, v, block)[0]
+
+
+def _causal_blocks_fwd(q, k, v, block):
+    o, lse = _causal_forward(q, k, v, block)
+    return o, (q, k, v, o, lse)
+
+
+def _causal_blocks_bwd(block, res, do):
+    q, k, v, o, lse = res
+    n = q.shape[2] // block
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    delta = jnp.sum(do.astype(F32) * o.astype(F32), axis=-1)  # (B,H,Sp)
+
+    def probs(i, j):
+        """p and dS of block (i, j)."""
+        s = _block_scores(_block(q, i, block), _block(k, j, block), i, j, block)
+        p = jnp.exp(s - _block(lse[..., None], i, block))
+        dp = jnp.einsum(
+            "bhqd,bhkd->bhqk", _block(do, i, block), _block(v, j, block),
+            preferred_element_type=F32,
+        )
+        return p, p * (dp - _block(delta[..., None], i, block))
+
+    def dq_row(i):
+        def step(j, dq):
+            _, ds = probs(i, j)
+            return dq + jnp.einsum(
+                "bhqk,bhkd->bhqd", ds.astype(k.dtype), _block(k, j, block),
+                preferred_element_type=F32,
+            )
+
+        dq0 = jnp.zeros(q.shape[:2] + (block, q.shape[-1]), F32)
+        return jax.lax.fori_loop(0, i + 1, step, dq0) * scale
+
+    def dkv_col(j):
+        def step(i, carry):
+            dk, dv = carry
+            p, ds = probs(i, j)
+            dv = dv + jnp.einsum(
+                "bhqk,bhqd->bhkd", p.astype(do.dtype), _block(do, i, block),
+                preferred_element_type=F32,
+            )
+            dk = dk + jnp.einsum(
+                "bhqk,bhqd->bhkd", ds.astype(q.dtype), _block(q, i, block),
+                preferred_element_type=F32,
+            )
+            return dk, dv
+
+        zeros = lambda t: jnp.zeros(t.shape[:2] + (block, t.shape[-1]), F32)
+        dk, dv = jax.lax.fori_loop(j, n, step, (zeros(k), zeros(v)))
+        return dk * scale, dv
+
+    def join(blocks, like):
+        return jnp.moveaxis(blocks, 0, 2).reshape(like.shape).astype(like.dtype)
+
+    dq = join(jax.lax.map(dq_row, jnp.arange(n)), q)
+    dk, dv = jax.lax.map(dkv_col, jnp.arange(n))
+    return dq, join(dk, k), join(dv, v)
+
+
+_causal_blocks.defvjp(_causal_blocks_fwd, _causal_blocks_bwd)
 
 
 def decode_attention(q, k_cache, v_cache, *, length=None, window: int | None = None, pos=None):

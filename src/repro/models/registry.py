@@ -23,6 +23,18 @@ class ModelBundle:
     forward: Callable  # (params, batch) -> logits
     prefill: Callable  # (params, batch, max_len) -> (logits, caches, pos)
     decode_step: Callable  # (params, token, caches, pos) -> (logits, caches)
+    # (params, batch, rng) -> (loss, stats), marked ``has_aux`` for the
+    # trainer, which then returns the stats in its metrics; None where the
+    # model has no counters
+    loss_and_stats: Callable | None = None
+
+
+def with_stats(fn: Callable) -> Callable:
+    """Mark a ``(params, batch, rng) -> (loss, stats)`` loss:
+    ``DecentralizedTrainer.local_step`` differentiates the loss alone and
+    returns the stats in its metrics."""
+    fn.has_aux = True
+    return fn
 
 
 def build_bundle(cfg: ModelConfig) -> ModelBundle:
@@ -60,6 +72,7 @@ def build_bundle(cfg: ModelConfig) -> ModelBundle:
             params, batch["tokens"], cfg, max_len
         ),
         decode_step=partial(tf.decode_step, cfg=cfg),
+        loss_and_stats=with_stats(partial(tf.lm_loss_and_stats, cfg=cfg)),
     )
 
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import hybrid as hybrid_mod
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.config import GroupCfg, LayerCfg, ModelConfig
@@ -44,6 +45,19 @@ PyTree = Any
 
 def _layer_params(key, cfg: ModelConfig, lc: LayerCfg):
     d, dtype = cfg.d_model, cfg.pdtype
+    if lc.kind in ("mla_mlp", "mla_moe"):
+        k1, k2 = jax.random.split(key)
+        ffn = (
+            {"moe": moe_mod.expert_share_params(k2, d, cfg.moe, dtype)}
+            if lc.kind == "mla_moe"
+            else {"mlp": mlp_params(k2, d, cfg.d_ff, dtype)}
+        )
+        return {
+            "ln1": jnp.zeros((d,), dtype),
+            "ln2": jnp.zeros((d,), dtype),
+            "attn": mla_mod.mla_params(k1, d, cfg.mla, dtype),
+            **ffn,
+        }
     if lc.kind == "attn_mlp":
         k1, k2 = jax.random.split(key)
         a = cfg.attn
@@ -108,7 +122,10 @@ def init_decoder_params(key, cfg: ModelConfig) -> PyTree:
 
 
 def _apply_layer(p, x, cfg: ModelConfig, lc: LayerCfg, positions):
+    """-> (x, aux loss, stats); stats is ``{}`` but for the expert-share
+    layer's counters."""
     cd = cfg.cdtype
+    zero = jnp.zeros((), F32)
     if lc.kind in ("attn_mlp", "moe"):
         a = cfg.attn
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -120,18 +137,26 @@ def _apply_layer(p, x, cfg: ModelConfig, lc: LayerCfg, positions):
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
         if lc.kind == "moe":
             out, aux = moe_mod.moe_apply(p["moe"], h, cfg.moe, cd)
-            return x + out, aux
-        return x + mlp_apply(p["mlp"], h, cd), jnp.zeros((), F32)
+            return x + out, aux, {}
+        return x + mlp_apply(p["mlp"], h, cd), zero, {}
+    if lc.kind in ("mla_mlp", "mla_moe"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        x = x + mla_mod.mla_apply(p["attn"], h, cfg.mla, positions, cd, cfg.norm_eps)
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if lc.kind == "mla_moe":
+            out, aux, stats = moe_mod.expert_share_apply(p["moe"], h, cfg.moe, cd)
+            return x + out, aux, stats
+        return x + mlp_apply(p["mlp"], h, cd), zero, {}
     if lc.kind == "mamba":
         h = rms_norm(x, p["ln"], cfg.norm_eps)
-        return x + ssm_mod.mamba_apply(p["mamba"], h, cfg.ssm, cfg.d_model, cd), jnp.zeros((), F32)
+        return x + ssm_mod.mamba_apply(p["mamba"], h, cfg.ssm, cfg.d_model, cd), zero, {}
     if lc.kind == "hymba":
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         x = x + hybrid_mod.hymba_mixer_apply(
             p["mixer"], h, cfg.attn, cfg.ssm, cfg.d_model, cd, lc.window
         )
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
-        return x + mlp_apply(p["mlp"], h, cd), jnp.zeros((), F32)
+        return x + mlp_apply(p["mlp"], h, cd), zero, {}
     raise ValueError(lc.kind)
 
 
@@ -139,14 +164,19 @@ def _apply_unit(p, x, cfg: ModelConfig, group: GroupCfg, positions):
     aux = jnp.zeros((), F32)
     if len(group.unit) == 1:
         return _apply_layer(p, x, cfg, group.unit[0], positions)
+    stats = {}
     for i, lc in enumerate(group.unit):
-        x, a = _apply_layer(p[f"sub{i}"], x, cfg, lc, positions)
+        x, a, st = _apply_layer(p[f"sub{i}"], x, cfg, lc, positions)
         aux = aux + a
-    return x, aux
+        if st:
+            stats[f"sub{i}"] = st
+    return x, aux, stats
 
 
-def decoder_stack(params, x, cfg: ModelConfig):
-    """Run all scanned groups over hidden states x (B, S, d).
+def decoder_stack(params, x, cfg: ModelConfig, with_stats: bool = False):
+    """Run all scanned groups over hidden states x (B, S, d) -> (x, aux),
+    or with ``with_stats`` (x, aux, stats): per group that has any, the
+    layers' counters stacked over the group's layers.
 
     In unroll mode (dry-run cost pass) the layer scan becomes a python loop
     with static slices so cost_analysis sees every layer's FLOPs."""
@@ -155,23 +185,29 @@ def decoder_stack(params, x, cfg: ModelConfig):
     S = x.shape[1]
     positions = jnp.arange(S)
     aux_total = jnp.zeros((), F32)
+    stats = {}
     for g in cfg.groups:
         if unroll_inner():
+            per_layer = []
             for r in range(g.repeat):
                 p_slice = jax.tree.map(lambda t: t[r], params[g.param_key])
-                x, a = _apply_unit(p_slice, x, cfg, g, positions)
+                x, a, st = _apply_unit(p_slice, x, cfg, g, positions)
                 aux_total = aux_total + a
-            continue
+                per_layer.append(st)
+            ys = jax.tree.map(lambda *t: jnp.stack(t), *per_layer)
+        else:
 
-        def body(carry, p_slice, g=g):
-            h, aux = carry
-            h, a = _apply_unit(p_slice, h, cfg, g, positions)
-            return (h, aux + a), None
+            def body(carry, p_slice, g=g):
+                h, aux = carry
+                h, a, st = _apply_unit(p_slice, h, cfg, g, positions)
+                return (h, aux + a), st
 
-        if cfg.remat:
-            body = jax.checkpoint(body)
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), params[g.param_key])
-    return x, aux_total
+            if cfg.remat:
+                body = jax.checkpoint(body)
+            (x, aux_total), ys = jax.lax.scan(body, (x, aux_total), params[g.param_key])
+        if ys:
+            stats[g.name] = ys
+    return (x, aux_total, stats) if with_stats else (x, aux_total)
 
 
 def embed_tokens(params, tokens, cfg: ModelConfig):
@@ -194,14 +230,48 @@ def forward(params, tokens, cfg: ModelConfig):
     return unembed(params, x, cfg), aux
 
 
-def lm_loss(params, batch, rng, cfg: ModelConfig):
-    """batch: {'tokens': (B, S+1)} -> mean CE + MoE aux."""
+def lm_loss_and_stats(params, batch, rng, cfg: ModelConfig):
+    """batch: {'tokens': (B, S+1)} -> (mean CE + MoE aux, stats): the
+    layers' counters (``decoder_stack``), ``{}`` where no layer gives any."""
     tokens = batch["tokens"]
-    logits, aux = forward(params, tokens[:, :-1], cfg)
+    x = embed_tokens(params, tokens[:, :-1], cfg)
+    x, aux, stats = decoder_stack(params, x, cfg, with_stats=True)
     mask = batch.get("mask")
     if mask is not None:
         mask = mask[:, 1:]
-    return softmax_cross_entropy(logits, tokens[:, 1:], mask) + aux
+    if cfg.head_chunk is not None:
+        return _chunked_cross_entropy(params, x, tokens[:, 1:], mask, cfg) + aux, stats
+    logits = unembed(params, x, cfg)
+    return softmax_cross_entropy(logits, tokens[:, 1:], mask) + aux, stats
+
+
+def _chunked_cross_entropy(params, x, labels, mask, cfg: ModelConfig):
+    """The mean next-token cross-entropy, ``cfg.head_chunk`` positions at a
+    time under ``jax.checkpoint``: the head's logits and their gradient
+    exist for one block at once."""
+    B, S, _ = x.shape
+    c = min(cfg.head_chunk, S)
+    mask = jnp.ones((B, S), F32) if mask is None else mask.astype(F32)
+    n = -(-S // c)  # a ragged tail is padded, and masked out
+
+    def blocks(t):
+        t = jnp.pad(t, [(0, 0), (0, n * c - S)] + [(0, 0)] * (t.ndim - 2))
+        return jnp.moveaxis(t.reshape(B, n, c, *t.shape[2:]), 1, 0)
+
+    @jax.checkpoint
+    def block(total, xs):
+        x_b, y_b, m_b = xs
+        logits = unembed(params, x_b, cfg).astype(F32)
+        nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, y_b[..., None], -1)[..., 0]
+        return total + jnp.sum(nll * m_b), None
+
+    total, _ = jax.lax.scan(block, jnp.zeros((), F32), (blocks(x), blocks(labels), blocks(mask)))
+    return total / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def lm_loss(params, batch, rng, cfg: ModelConfig):
+    """batch: {'tokens': (B, S+1)} -> mean CE + MoE aux."""
+    return lm_loss_and_stats(params, batch, rng, cfg)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -298,8 +368,17 @@ def _decode_layer(p, x, cache, pos, cfg: ModelConfig, lc: LayerCfg):
     raise ValueError(lc.kind)
 
 
+def _refuse_latent_attention(cfg: ModelConfig):
+    if any(ref.lc.kind in ("mla_mlp", "mla_moe") for ref in iter_layers(cfg)):
+        raise NotImplementedError(
+            f"{cfg.name}: serving latent-attention layers needs an MLA decode "
+            "cache, which this engine does not have (training only)"
+        )
+
+
 def decode_step(params, token, caches, pos, cfg: ModelConfig):
     """One serving step: token (B, 1) + caches @ pos -> (logits (B,1,V), caches)."""
+    _refuse_latent_attention(cfg)
     x = embed_tokens(params, token, cfg)
     new_caches = []
     for i, ref in enumerate(iter_layers(cfg)):
@@ -327,6 +406,7 @@ def prefill(params, tokens, cfg: ModelConfig, max_len: int):
     """Full-sequence prefill building decode caches.
 
     Returns (logits of the LAST position (B, 1, V), caches, next_pos)."""
+    _refuse_latent_attention(cfg)
     B, S = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.arange(S)

@@ -1,11 +1,12 @@
-"""Mosaic compiles of the main-path consensus kernels for a TPU v5e chip.
+"""Mosaic compiles of the main-path kernels for a TPU v5e chip.
 
 Each kernel is lowered with ``interpret=False`` and compiled for a described
 (not attached) ``v5e:2x2`` topology at the paper job's widths: K=16 agents,
 the ResNet-20 (width 16) slab of D=309,248 columns, ring edges; and the
 exact combine once more at one h2o-danube-3-4b layer's width (K=2, one
 region of 154,836,480 columns), where its tile is widest, with four K=2
-slots to a block, and at K=64, the most agents the exact path serves.  Nothing
+slots to a block, and at K=64, the most agents the exact path serves; and
+the held experts' grouped matmuls at the kanana cell's widths.  Nothing
 runs; a compile that passes proves Mosaic accepts the block shapes, the
 in-kernel ops and the VMEM budget, and ``tpu_custom_call`` in the compiled
 text proves a Mosaic kernel (not the interpreter) is in the program.
@@ -28,12 +29,13 @@ from repro import kernels as kk
 from repro.core.dynamic import edge_stacks_from_topology, max_in_degree_from_topology
 from repro.core.packing import build_slab_layout
 from repro.core.topology import ring
+from repro.kernels.moe_gmm import moe_gmm
 from repro.models.resnet import init_resnet20
 from repro.utils.pytree import LayerPartition
 
 K = 16
 
-I32, U32, F32, I8 = jnp.int32, jnp.uint32, jnp.float32, jnp.int8
+I32, U32, F32, I8, BF16 = jnp.int32, jnp.uint32, jnp.float32, jnp.int8, jnp.bfloat16
 
 
 @pytest.fixture(scope="module")
@@ -163,7 +165,25 @@ def _cases(p):
             lambda x, y: kk.drt_dist(x, y, interpret=False),
             (s((78080,)), s((78080,))),  # one stage-3 slot
         ),
+        "moe_gmm": (  # the kanana cell's held experts: forward and both gradients, per agent
+            _held_experts_step,
+            (s((2, 49152, 2048), BF16), s((2, 8, 2048, 1536), BF16), s((2, 8, 768, 2048), BF16),
+             s((2, 8), I32)),
+        ),
     }
+
+
+def _held_experts_step(x, w_in, w_down, sizes):
+    """The SiLU-gated held experts over a 49,152-row buffer (8,192 tokens x
+    top-6), d 2048, f 768, 8 groups, K=2 agents: ``moe_gmm`` forward and
+    input gradient, ``moe_tgmm`` weight gradient."""
+
+    def loss(x, w_in, w_down, sizes):
+        h = moe_gmm(x, w_in, sizes, False)
+        y = moe_gmm(jax.nn.silu(h[:, :768]) * h[:, 768:], w_down, sizes, False)
+        return jnp.sum(y.astype(F32))
+
+    return jax.vmap(jax.grad(loss, (0, 1, 2)))(x, w_in, w_down, sizes)
 
 
 @pytest.mark.parametrize(
@@ -181,6 +201,7 @@ def _cases(p):
         "slab_edge_encode_combine_exact",
         "slab_edge_encode_combine_int8",
         "drt_dist",
+        "moe_gmm",
     ],
 )
 def test_kernel_compiles_for_v5e(name, paper, no_compile_cache):

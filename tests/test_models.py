@@ -68,6 +68,16 @@ def test_full_configs_match_assignment():
     fm = get_config("falcon-mamba-7b")
     assert fm.n_layers == 64 and fm.d_model == 4096 and fm.vocab == 65024
     assert fm.attn is None and fm.ssm.d_state == 16
+    kn = get_config("kanana-2-30b-a3b")  # latent attention: no GQA heads to list
+    assert kn.n_layers == 48 and kn.d_model == 2048 and kn.vocab == 128256 and kn.d_ff == 6144
+    assert kn.groups[0].n_layers == 1 and kn.groups[0].unit[0].kind == "mla_mlp"
+    m = kn.mla
+    assert (m.n_heads, m.kv_lora_rank, m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim) == (
+        32, 512, 128, 64, 128,
+    )
+    e = kn.moe
+    assert (e.n_experts, e.top_k, e.d_ff_expert, e.shared_d_ff, e.held) == (128, 6, 768, 1536, 128)
+    assert e.routed_scale == 2.448 and kn.head_chunk == 1024
 
 
 def test_moe_expert_counts():
@@ -224,5 +234,12 @@ def test_flash_attention_matches_naive():
 
     for window in (None, 16):
         got = flash_attention(q, k, v, causal=True, window=window, kv_chunk=32, q_chunk=32)
+        want = naive(q, k, v, window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    # a value head width other than the query/key width (latent attention)
+    v = jax.random.normal(jax.random.key(3), (B_, S_, 2, 24))
+    for window in (None, 16):
+        got = flash_attention(q, k, v, causal=True, window=window, kv_chunk=32, q_chunk=32)
+        assert got.shape == (B_, S_, H, 24)
         want = naive(q, k, v, window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
